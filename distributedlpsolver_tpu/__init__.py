@@ -32,38 +32,25 @@ if not os.environ.get("TPULP_NO_X64"):
     jax.config.update("jax_enable_x64", True)
 
 if not os.environ.get("TPULP_NO_COMPILE_CACHE"):
-    # Persistent XLA compilation cache. The emulated-f64 batched programs
-    # compile in minutes on TPU (measured: 237 s for the batched f64 step
-    # at the reference's 1024×(128,512) config) but run in ~1 s — caching
-    # the executable makes every process after the first start warm.
-    # Opt out with TPULP_NO_COMPILE_CACHE=1 or point TPULP_COMPILE_CACHE
-    # somewhere else (default: .tpulp_xla_cache next to this package's
-    # parent, i.e. inside the checkout).
+    # Persistent XLA compilation cache, so a process after the first
+    # starts warm. One rule: JAX_COMPILATION_CACHE_DIR (read by JAX
+    # itself) names the directory when it is set, and nothing here
+    # replaces it; otherwise the cache lives at the fixed path
+    # <checkout>/.tpulp_xla_cache. The path is part of what makes a
+    # cache hit, so it never depends on the working directory or a
+    # temp dir. Opt out with TPULP_NO_COMPILE_CACHE=1.
     import jax
 
-    # Default next to the checkout when that is writable (a source tree —
-    # keeps the cache with the project); for installed packages (read-only
-    # site-packages) fall back to the user cache dir.
-    _parent = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if os.access(_parent, os.W_OK):
-        _default_cache = os.path.join(_parent, ".tpulp_xla_cache")
-    else:
-        _default_cache = os.path.join(
-            os.environ.get(
-                "XDG_CACHE_HOME", os.path.join(os.path.expanduser("~"), ".cache")
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update(
+            "jax_compilation_cache_dir",
+            os.path.join(
+                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                ".tpulp_xla_cache",
             ),
-            "tpulp_xla_cache",
         )
-    # An explicit TPULP_COMPILE_CACHE always wins — even over a dir JAX
-    # already picked up from JAX_COMPILATION_CACHE_DIR; otherwise only
-    # fill in the default when nothing is configured.
-    _cache_dir = os.environ.get("TPULP_COMPILE_CACHE") or (
-        None if jax.config.jax_compilation_cache_dir else _default_cache
-    )
-    if _cache_dir:
-        jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 __version__ = "0.1.0"
 

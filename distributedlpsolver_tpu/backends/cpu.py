@@ -4,7 +4,7 @@ The reference's baseline execution is CPU ranks with LAPACK-backed dense
 kernels (SURVEY.md §2 "CPU dense backend", [INFERRED] LAPACK/NumPy
 potrf/trsv). This backend runs the *same* algorithm core as the device
 backends (ipm/core.py with ``xp=numpy``) — it exists to (a) be the
-measured baseline the TPU path is compared against (BASELINE.md), (b)
+measured baseline the TPU path is compared against, (b)
 cross-check the JAX backends with a fully independent execution engine,
 and (c) carry the native C++ kernels (backends/cpu_native.py) the way the
 reference's CPU path sits on LAPACK.

@@ -96,13 +96,6 @@ def _add_solver_flags(ap: argparse.ArgumentParser) -> None:
         "after device loss before degrading to the next backend",
     )
     ap.add_argument(
-        "--jax-cache-dir",
-        default=None,
-        help="persistent JAX/XLA compilation cache directory — restarts "
-        "skip every compile cached by an earlier run (cold-bucket serve "
-        "compiles included); logs a hit/miss line at startup",
-    )
-    ap.add_argument(
         "--metrics-path",
         default=None,
         help="enable the obs/ metrics registry and write a "
@@ -116,24 +109,25 @@ def _add_solver_flags(ap: argparse.ArgumentParser) -> None:
     )
 
 
-def _apply_jax_cache(args) -> None:
-    """Point JAX's persistent compilation cache at --jax-cache-dir (wins
-    over the package default) and log the startup hit/miss line."""
-    d = getattr(args, "jax_cache_dir", None)
-    if not d:
-        return
+def _log_jax_cache() -> None:
+    """Log the startup hit/miss line for the persistent compilation cache
+    JAX was given (JAX_COMPILATION_CACHE_DIR, else the package's fixed
+    in-checkout default — see the package ``__init__``)."""
     import os
 
     import jax
 
-    os.makedirs(d, exist_ok=True)
-    n = sum(1 for f in os.listdir(d) if not f.startswith("."))
-    jax.config.update("jax_compilation_cache_dir", d)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    d = jax.config.jax_compilation_cache_dir
+    if not d:
+        print("jax compilation cache: off", file=sys.stderr)
+        return
+    n = (
+        sum(1 for f in os.listdir(d) if not f.startswith("."))
+        if os.path.isdir(d) else 0
+    )
     print(
-        f"jax compilation cache: {d} — {n} cached programs "
-        f"({'warm start, cold compiles will be cache hits' if n else 'cold start, compiles will be cached'})",
+        f"jax compilation cache: {d} — {n} cached entries "
+        f"({'warm start, cold compiles may be cache hits' if n else 'cold start, compiles will be cached'})",
         file=sys.stderr,
     )
 
@@ -297,7 +291,7 @@ def _report(result, as_json: bool, x_out: Optional[str]) -> int:
 def cmd_solve(args) -> int:
     from distributedlpsolver_tpu.io.mps import read_mps
 
-    _apply_jax_cache(args)
+    _log_jax_cache()
     finalize_obs = _obs_setup(args)
     try:
         return _cmd_solve_inner(args, read_mps(args.file))
@@ -467,7 +461,7 @@ def cmd_serve(args) -> int:
         SolveService,
     )
 
-    _apply_jax_cache(args)
+    _log_jax_cache()
     finalize_obs = _obs_setup(args)
     svc_cfg = _service_config_from(args)
     out = sys.stdout if args.out == "-" else open(args.out, "w")
@@ -545,7 +539,7 @@ def cmd_serve_http(args) -> int:
     from distributedlpsolver_tpu.obs import metrics as obs_metrics
     from distributedlpsolver_tpu.serve import SolveService
 
-    _apply_jax_cache(args)
+    _log_jax_cache()
     finalize_obs = _obs_setup(args)
     svc_cfg = _service_config_from(args)
     net_cfg = NetConfig(
@@ -747,7 +741,7 @@ def cmd_serve_slice(args) -> int:
         from distributedlpsolver_tpu.obs import metrics as obs_metrics
         from distributedlpsolver_tpu.serve import SolveService
 
-        _apply_jax_cache(args)
+        _log_jax_cache()
         finalize_obs = _obs_setup(args)
         runner = SliceRunner(world, FileControlPlane(ctrl_dir), solver_cfg)
         svc_cfg = _service_config_from(args)

@@ -1,13 +1,15 @@
 """Single-machine multi-process world launcher + coordinator recovery.
 
-The harness half of the multi-host runtime: spawn N rank processes with
-the world env contract (distributed/world.py), each pinned to
-``JAX_PLATFORMS=cpu`` with ``--xla_force_host_platform_device_count=K``
-virtual devices, all joined through one ``jax.distributed`` coordinator
-on a freshly allocated localhost port. This is tier-1-testable today and
-maps 1:1 onto a real TPU pod slice: there the per-host agent exports the
-same env (coordinator = worker 0, one process per host, devices = the
-host's chips) and everything above this module is identical.
+This is a CPU harness, and no chip path goes through it. It spawns N
+rank processes with the world env contract (distributed/world.py), each
+pinned to ``JAX_PLATFORMS=cpu`` with
+``--xla_force_host_platform_device_count=K`` virtual devices, all joined
+through one ``jax.distributed`` coordinator on a freshly allocated
+localhost port. A chip belongs to one process, so a parent holding a
+TPU cannot hand it to ranks it spawns; on a real pod slice the per-host
+agent exports the same env (coordinator = worker 0, one process per
+host, devices = the host's chips) and everything above this module is
+identical.
 
 Coordinator-level recovery (the missing supervisor rung): a
 ``jax.distributed`` world DIES AS A UNIT when any rank is lost — XLA's
@@ -179,16 +181,15 @@ def launch_world(
     coordinator_port: Optional[int] = None,
     slice_id: Optional[str] = None,
     extra_env: Optional[dict] = None,
-    platform: str = "cpu",
 ) -> WorldHandle:
-    """Spawn one world of ``world_size`` rank processes.
+    """Spawn one world of ``world_size`` CPU rank processes.
 
     ``argv_for(rank)`` builds each rank's command line (usually the
     worker entry or ``cli serve-slice --rank N``). The launcher owns the
     env contract: coordinator address, rank/world size, virtual-device
-    flags, heartbeat dir (per generation — a relaunch never reads the
-    dead world's beats), and the persistent compilation cache dir, which
-    all ranks share so a relaunched world's compiles are cache hits.
+    flags and heartbeat dir (per generation — a relaunch never reads the
+    dead world's beats). Ranks share the package's one persistent
+    compilation cache, so a relaunched world's compiles are cache hits.
     """
     os.makedirs(workdir, exist_ok=True)
     port = coordinator_port or free_port()
@@ -196,25 +197,23 @@ def launch_world(
     handle = WorldHandle([], workdir, coordinator, generation, world_size)
     os.makedirs(handle.heartbeat_dir, exist_ok=True)
     os.makedirs(handle.out_dir, exist_ok=True)
-    cache_dir = os.path.join(workdir, "xla-cache")
     procs: List[RankProcess] = []
     for rank in range(world_size):
         env = dict(os.environ)
         env.update(extra_env or {})
-        if platform == "cpu":
-            env["JAX_PLATFORMS"] = "cpu"
-            flags = env.get("XLA_FLAGS", "")
-            # Every rank gets its OWN device-count flag (strip any
-            # inherited one — the pytest conftest exports 8).
-            flags = " ".join(
-                f
-                for f in flags.split()
-                if "xla_force_host_platform_device_count" not in f
-            )
-            env["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count="
-                f"{local_devices}"
-            ).strip()
+        env["JAX_PLATFORMS"] = "cpu"
+        flags = env.get("XLA_FLAGS", "")
+        # Every rank gets its OWN device-count flag (strip any
+        # inherited one — the pytest conftest exports 8).
+        flags = " ".join(
+            f
+            for f in flags.split()
+            if "xla_force_host_platform_device_count" not in f
+        )
+        env["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count="
+            f"{local_devices}"
+        ).strip()
         env[world_lib.ENV_COORDINATOR] = coordinator
         env[world_lib.ENV_RANK] = str(rank)
         env[world_lib.ENV_WORLD_SIZE] = str(world_size)
@@ -223,7 +222,6 @@ def launch_world(
         env[world_lib.ENV_WORLD_GEN] = str(generation)
         if slice_id:
             env[world_lib.ENV_SLICE_ID] = slice_id
-        env.setdefault("TPULP_COMPILE_CACHE", cache_dir)
         log_path = os.path.join(
             workdir, f"gen{generation}-rank{rank}.log"
         )

@@ -345,14 +345,10 @@ def init_world(cfg: Optional[WorldConfig] = None) -> World:
                 f"world_size={cfg.world_size} needs a coordinator address "
                 f"({ENV_COORDINATOR})"
             )
-        # Cross-process CPU collectives (the single-machine harness and
-        # any CPU fallback host): gloo ships in jaxlib; without it every
-        # cross-process psum would fail at dispatch. TPU worlds ignore
-        # this knob (ICI/DCN collectives are native).
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass  # older/newer jax without the option: platform default
+        # Cross-process CPU collectives (the single-machine harness):
+        # gloo ships in jaxlib; without it every cross-process psum
+        # would fail at dispatch.
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(
             coordinator_address=cfg.coordinator,
             num_processes=cfg.world_size,

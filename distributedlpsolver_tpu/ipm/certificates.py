@@ -2,7 +2,7 @@
 
 Upgrades the driver's divergence *heuristics* (core.classify_divergence)
 to checkable mathematical certificates, extracted from the diverging
-iterate on the host (VERDICT.md round 1, item 10; the reference has no
+iterate on the host (review round 1, item 10; the reference has no
 such machinery on available evidence — SURVEY.md §5.3 — so this is a
 capability addition, not a parity item).
 

@@ -61,7 +61,7 @@ class Status(enum.Enum):
 class FaultKind(enum.Enum):
     """Classification of a solve fault observed by the supervisor.
 
-    The taxonomy mirrors the production failure classes: a device dispatch
+    The classes mirror the production failure classes: a device dispatch
     that never returns (``HANG``, the watchdog's deadline fired), an
     iterate whose host-side convergence scalars went non-finite or μ
     exploded (``NUMERICAL``), a backend step that raised outright

@@ -10,7 +10,7 @@ from them), so tests can assert convergence unconditionally.
 NOTE: the true Netlib/Mittelmann files (afiro, pds-*, neos3, stormG2_1000)
 cannot be downloaded in this zero-egress environment; `bench.py` uses these
 generators at the published shapes and the MPS reader accepts the real files
-whenever they are dropped into ``data/`` (see BASELINE.md).
+whenever they are dropped into ``data/``.
 """
 
 from __future__ import annotations

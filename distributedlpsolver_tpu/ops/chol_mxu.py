@@ -8,7 +8,7 @@ recurrences whose every scalar op pays the f64-emulation tax. Meanwhile
 emulated-f64 *GEMM* runs at ~150 GFLOP/s with 2.2e-15 max relative error
 (the MXU split path), and fused f64 elementwise streams at ~2 ns/element.
 Round 4 misattributed the batched backend's wall to "emulated-f64
-elementwise" (BASELINE.md batched row); the component probe shows the
+elementwise"; the component probe shows the
 factorization and triangular solves own ~75% of the 622 ms step.
 
 So: restructure the factorization so ALL O(m³) work is GEMM and the only

@@ -1,6 +1,6 @@
 """Mesh-distributed Cholesky factorization + triangular inversion.
 
-The second distributed-factorization cut (SURVEY.md §2.2; VERDICT round 3
+The second distributed-factorization cut (SURVEY.md §2.2; review round 3
 item 6). Round 3 sharded the TRSM slabs of L⁻¹ (`dense._tri_inv_mesh`),
 but the m×m Cholesky itself — and the full M and L it reads and writes —
 stayed REPLICATED on every device: per-device HBM held 3 full m×m buffers

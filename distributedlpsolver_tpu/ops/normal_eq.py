@@ -30,11 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names the Mosaic compiler-params class TPUCompilerParams.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", None
-) or getattr(pltpu, "TPUCompilerParams")
-
 
 def _round_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
@@ -140,7 +135,7 @@ def normal_eq_pallas(
         out_specs=pl.BlockSpec((block_m, block_m), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, mp), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_m), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

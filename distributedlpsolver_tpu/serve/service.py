@@ -2219,10 +2219,10 @@ class SolveService:
 
     @staticmethod
     def _cache_dir_snapshot():
-        """(dir, entries) of JAX's persistent compilation cache — the
-        ``--jax-cache-dir`` satellite: warm-up compiles go through it
-        when configured, and the per-bucket warmup line classifies each
-        compile as a cache hit (no new entry written) or miss."""
+        """(dir, entries) of JAX's persistent compilation cache: warm-up
+        compiles go through it when configured, and the per-bucket
+        warmup line classifies each compile as a cache hit (no new entry
+        written) or miss."""
         import os
 
         import jax
@@ -2246,7 +2246,7 @@ class SolveService:
         traffic never pays those compiles. Idempotent per warm key.
 
         Compiles go through the persistent compilation cache when one is
-        configured (``--jax-cache-dir`` / TPULP_COMPILE_CACHE), and every
+        configured (JAX_COMPILATION_CACHE_DIR or the package default), and every
         warmed bucket logs a ``cache: hit|miss|off`` line — ``hit`` means
         the executable was served without writing a new cache entry (a
         restart after a ladder swap pays deserialization, not XLA), so

@@ -1,13 +1,9 @@
-"""Accelerator-presence guard for measurement envelopes.
+"""Accelerator-presence guard for measurement entry points.
 
-BENCH_r05 is the cautionary tale: the requested TPU was unavailable,
-bench.py fell back to host CPU, and a whole measurement round produced
-rows that — although honestly stamped ``"platform": "cpu-fallback"`` —
-were unquotable and had to be thrown away (ROADMAP "Perf trajectory").
-Stamping makes a bad round *detectable*; this guard makes it
-*impossible*: ``bench.py --require-tpu`` and the ``scripts/run_*``
-envelopes hard-fail up front instead of spending hours measuring the
-wrong platform.
+A figure measured on the wrong platform is worse than none, so
+``bench.py`` and the ``scripts/run_*`` TPU envelopes call
+:func:`require_tpu` before measuring anything: no TPU means a non-zero
+exit, never a switch to the CPU.
 """
 
 from __future__ import annotations
@@ -17,30 +13,24 @@ import sys
 REQUIRE_TPU_EXIT = 4  # distinct from solve-failure (2/3) exit codes
 
 
-def require_tpu(enabled: bool = True) -> None:
+def require_tpu() -> None:
     """Hard-fail (``SystemExit`` with code :data:`REQUIRE_TPU_EXIT`)
-    unless jax's default backend is TPU. With ``enabled=False`` this is
-    a no-op, so callers can write ``require_tpu("--require-tpu" in
-    sys.argv)``. Must run before any fallback logic rewrites
-    ``jax_platforms``."""
-    if not enabled:
-        return
+    unless jax's default backend is TPU."""
     import jax
 
     try:
         backend = jax.default_backend()
     except RuntimeError as e:
         print(
-            f"--require-tpu: accelerator initialization failed ({e}); "
-            "refusing to fall back to CPU",
+            f"accelerator initialization failed ({e}); refusing to "
+            "measure on the CPU",
             file=sys.stderr,
         )
         raise SystemExit(REQUIRE_TPU_EXIT)
     if backend != "tpu":
         print(
-            f"--require-tpu: default backend is {backend!r}, not TPU — "
-            "aborting before any figure is produced (a fallback round "
-            "is a wasted round, see BENCH_r05)",
+            f"default backend is {backend!r}, not TPU — aborting before "
+            "any figure is produced",
             file=sys.stderr,
         )
         raise SystemExit(REQUIRE_TPU_EXIT)

@@ -1,4 +1,4 @@
-"""Utilization folding for phase reports (VERDICT round 3 item 4).
+"""Utilization folding for phase reports (review round 3 item 4).
 
 `core.drive_phase_plan` records one ``{"phase", "iters", "wall_s"}`` row
 per phase; the backends stamp each row's ``"mode"`` from their own plan
@@ -7,7 +7,7 @@ that into the utilization fields the scale artifacts record: effective
 FLOP/s per assembly-bound phase, its percentage of the watchdog seed
 rates (`core.SEG_RATE_F32`/`SEG_RATE_F64` — the conservative per-dtype
 device rates every backend already budgets segments with), and — the
-honest number (VERDICT round 4 item 9) — its percentage of the CHIP's
+honest number (review round 4 item 9) — its percentage of the CHIP's
 peak for that arithmetic class. The two percentages answer different
 questions: ``pct_of_seed_rate`` is budget-relative (is the phase running
 at the rate its watchdog segments were sized for?), while
@@ -22,20 +22,26 @@ be fiction — their rows still carry the measured iters/wall split.
 
 from __future__ import annotations
 
-# Chip peaks for the utilization denominator, one TPU v5 lite (v5e) chip:
-# ~197 TFLOP/s bf16 MXU; f32 matmul runs as bf16x3/x6 passes (~1/4 of
-# bf16 → ~49 TFLOP/s usable f32 peak). Emulated f64 has no hardware
-# peak; its practical ceiling is the measured MXU-split GEMM rate on
-# this chip (~1.8e11 FLOP/s, scripts/probe_chol_mxu.py) — "100%" for
-# f64 phases therefore means "at the platform's software-f64 GEMM
-# ceiling", which is the only meaningful roofline for that class.
-CHIP_PEAK_F32 = 4.9e13
-CHIP_PEAK_F64_SW = 1.8e11
+# Chip peaks for the utilization denominator, keyed by the device's
+# ``device_kind``. A kind missing from the table gets NO
+# ``pct_of_chip_peak`` — a guessed denominator would be a made-up number.
+#
+# "TPU v5 lite" (v5e): 197 TFLOP/s bf16 MXU, 819 GB/s HBM (Google Cloud
+# documentation, "TPU v5e"). f32 matmuls at HIGHEST precision run as
+# multi-pass bf16 (~1/4 of the bf16 rate → ~49 TFLOP/s usable f32).
+# Emulated f64 has no hardware peak; its ceiling is the MXU-split GEMM
+# rate measured on this chip (~1.8e11 FLOP/s, scripts/probe_chol_mxu.py),
+# so "100%" for f64 phases means "at the software-f64 GEMM ceiling".
+CHIP_PEAKS = {
+    "TPU v5 lite": {"f32": 4.9e13, "f64_sw": 1.8e11},
+}
 
 
-def fold_utilization(report, flops_per_iter: float):
+def fold_utilization(report, flops_per_iter: float, device_kind=None):
     """Annotate ``report`` rows (in place) with ``eff_flops_per_s``,
-    ``pct_of_seed_rate``, and ``pct_of_chip_peak``; returns the list.
+    ``pct_of_seed_rate``, and — when ``device_kind`` (default: the first
+    JAX device's) has a row in :data:`CHIP_PEAKS` — ``pct_of_chip_peak``;
+    returns the list.
 
     ``flops_per_iter`` is the backend's own per-iteration estimate for
     the direct factorization path (e.g. ``BlockAngularBackend._f64_flops``)
@@ -44,20 +50,28 @@ def fold_utilization(report, flops_per_iter: float):
     """
     from distributedlpsolver_tpu.ipm import core
 
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    peaks = CHIP_PEAKS.get(device_kind)
     rates = {
-        "f32": (core.SEG_RATE_F32, CHIP_PEAK_F32),
-        "mixed": (core.SEG_RATE_F32, CHIP_PEAK_F32),
-        "f64": (core.SEG_RATE_F64, CHIP_PEAK_F64_SW),
-        "f64c": (core.SEG_RATE_F64, CHIP_PEAK_F64_SW),
+        "f32": (core.SEG_RATE_F32, "f32"),
+        "mixed": (core.SEG_RATE_F32, "f32"),
+        "f64": (core.SEG_RATE_F64, "f64_sw"),
+        "f64c": (core.SEG_RATE_F64, "f64_sw"),
     }
     for ph in report:
         pair = rates.get(ph.get("mode"))
         if pair and ph.get("iters") and ph.get("wall_s", 0) > 0:
-            seed, peak = pair
+            seed, peak_key = pair
             eff = flops_per_iter * ph["iters"] / ph["wall_s"]
             ph["eff_flops_per_s"] = f"{eff:.3g}"
             ph["pct_of_seed_rate"] = round(100.0 * eff / seed, 1)
-            ph["pct_of_chip_peak"] = round(100.0 * eff / peak, 2)
-            if ph["mode"] in ("f64", "f64c"):
-                ph["chip_peak_basis"] = "software-f64 GEMM ceiling"
+            if peaks is not None:
+                ph["pct_of_chip_peak"] = round(
+                    100.0 * eff / peaks[peak_key], 2
+                )
+                if peak_key == "f64_sw":
+                    ph["chip_peak_basis"] = "software-f64 GEMM ceiling"
     return report

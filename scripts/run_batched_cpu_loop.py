@@ -1,7 +1,7 @@
 """Measured FULL-LOOP CPU baseline for the batched config (B:11):
 all 1024 (128x512) members solved one at a time through `cpu-native` —
 the reference's natural "one LP per rank" shape — with NO sampling or
-extrapolation (VERDICT round-4 item 1 demanded a measured loop).
+extrapolation (review round-4 item 1 demanded a measured loop).
 
 Wall vs process-CPU time both recorded (1-core host: a gap flags
 contention, the round-4 lesson)."""

@@ -1,4 +1,4 @@
-"""CPU baseline for the dense 2048x10240 config (VERDICT round-4 item 3):
+"""CPU baseline for the dense 2048x10240 config (review round-4 item 3):
 end-to-end `cpu-native` solve to 1e-8 on the exact suite-row instance
 (random_dense_lp(2048, 10240, seed=2) — bench.py's [3/6] row at the
 --quick size that the suite actually times on TPU).

@@ -1,4 +1,4 @@
-"""neos3-class UNSTRUCTURED sparse row (VERDICT round-4 item 6 /
+"""neos3-class UNSTRUCTURED sparse row (review round-4 item 6 /
 BASELINE.json:10): a sparse LP whose random pattern defeats block-angular
 detection, measured through BOTH candidate executors at 1e-8 —
 `cpu-sparse` (the sparse-direct host path the auto rule routes to) and
@@ -11,13 +11,10 @@ import json, resource, sys, time
 
 sys.path.insert(0, "/root/repo")
 
-# Measurement envelope: `--require-tpu` aborts (exit 4) instead of
-# silently measuring host CPU when the accelerator is missing (the
-# BENCH_r05 failure class).
+# Measurement envelope: no TPU aborts (exit 4) before anything is measured.
 from distributedlpsolver_tpu.utils.accel import require_tpu
 
-require_tpu("--require-tpu" in sys.argv)
-sys.argv = [a for a in sys.argv if a != "--require-tpu"]
+require_tpu()
 import numpy as np
 
 m, n, density = 20000, 40000, 0.0005

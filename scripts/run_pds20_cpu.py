@@ -1,4 +1,4 @@
-"""CPU baseline for the pds-20-class block-angular config (VERDICT item 2):
+"""CPU baseline for the pds-20-class block-angular config (review item 2):
 the sparse-direct CPU backend on the ~30k-row K=64 instance."""
 import json, sys, time
 import jax

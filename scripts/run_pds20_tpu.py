@@ -1,4 +1,4 @@
-"""pds-20-class Schur-path run (VERDICT round 2 item 2): the K=64,
+"""pds-20-class Schur-path run (review round 2 item 2): the K=64,
 432x1400-per-block, 1600 linking-row instance (~29k rows) on the TPU
 block backend (two-phase segmented Schur), plus an optional 8-virtual-
 device mesh run proving the K-sharded memory story.
@@ -17,19 +17,18 @@ import time
 
 sys.path.insert(0, "/root/repo")
 
-# Measurement envelope: `--require-tpu` aborts (exit 4) instead of
-# silently measuring host CPU when the accelerator is missing (the
-# BENCH_r05 failure class).
-from distributedlpsolver_tpu.utils.accel import require_tpu
-
-require_tpu("--require-tpu" in sys.argv)
-sys.argv = [a for a in sys.argv if a != "--require-tpu"]
 
 on_mesh = len(sys.argv) > 1 and sys.argv[1] == "mesh"
 if on_mesh:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
+else:
+    # Measurement envelope: no TPU aborts (exit 4) before anything is
+    # measured.
+    from distributedlpsolver_tpu.utils.accel import require_tpu
+
+    require_tpu()
 
 from distributedlpsolver_tpu.ipm import solve
 from distributedlpsolver_tpu.models.generators import block_angular_lp
@@ -81,7 +80,7 @@ row = {
     "objective": float(r.objective),
 }
 
-# Utilization (VERDICT round 3 item 4): per-phase wall split from the
+# Utilization (review round 3 item 4): per-phase wall split from the
 # shared segment driver, FLOP/s vs seed rates keyed by the
 # backend-recorded phase mode (utils/utilization.py).
 from distributedlpsolver_tpu.utils.utilization import fold_utilization

@@ -1,5 +1,5 @@
 """storm-100k A/B harness for the f64 program-class fault (ROUND5_NOTES
-lever 4, VERDICT round-4 item 8): the ≥100k-row storm class binds on an
+lever 4, review round-4 item 8): the ≥100k-row storm class binds on an
 f64 phase KERNEL fault — the worker crashes on the big-K batched f64
 programs — not on HBM, while chunk ≤128 program shapes stay healthy.
 This script runs the SAME hint-less storm-class instance through two
@@ -19,11 +19,10 @@ measure nothing.
 Default shape: K=1024 blocks of 96x192 with 64 linking rows
 = 98,368 + 64 rows (~100k), sparse, arriving hint-less.
 
-Measurement envelope: ``--require-tpu`` aborts with exit 4 instead of
-silently measuring host CPU when the accelerator is missing (the
-BENCH_r05 failure class). Off-TPU the harness still runs (CPU has no
-program-class fault to reproduce, but the A/B plumbing stays testable
-on small shapes).
+A chip belongs to one process, so the parent never touches JAX: each
+arm claims the chip itself and aborts with exit 4 when there is no TPU
+(no figure is measured on the CPU). The platform each arm ran on is
+recorded in its row.
 
 Writes /root/repo/.storm100k.json. Optional argv: K mb nb link density.
 """
@@ -34,11 +33,6 @@ import sys
 import time
 
 sys.path.insert(0, "/root/repo")
-
-from distributedlpsolver_tpu.utils.accel import require_tpu
-
-require_tpu("--require-tpu" in sys.argv)
-sys.argv = [a for a in sys.argv if a != "--require-tpu"]
 
 
 def _shape():
@@ -53,15 +47,18 @@ def _shape():
 def _arm_main(out_path):
     """One arm: build, detect, solve — grouping already fixed by the
     parent's DLPS_BLOCK_K_GROUP before this interpreter imported jax."""
+    import jax
     import jax.numpy as jnp
 
     from distributedlpsolver_tpu.backends import block_angular as ba
     from distributedlpsolver_tpu.ipm import solve
     from distributedlpsolver_tpu.models.generators import block_angular_lp
     from distributedlpsolver_tpu.models.structure import detect_block_structure
+    from distributedlpsolver_tpu.utils.accel import require_tpu
 
+    require_tpu()
     K, mb, nb, link, density = _shape()
-    out = {"k_group": ba._K_GROUP}
+    out = {"k_group": ba._K_GROUP, "platform": jax.devices()[0].platform}
     print(f"[arm k_group={ba._K_GROUP}] building K={K} {mb}x{nb} "
           f"link={link} density={density}...", flush=True)
     t0 = time.time()
@@ -122,10 +119,6 @@ out = {
               f"density={density}), HINT-LESS, A/B oneshot vs kgroup",
     "arms": {},
 }
-import jax
-
-out["platform"] = jax.devices()[0].platform
-
 for name, group in (("oneshot", "0"), ("kgroup", "128")):
     arm_path = f"/root/repo/.storm100k.{name}.json"
     env = dict(os.environ, DLPS_BLOCK_K_GROUP=group)

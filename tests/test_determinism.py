@@ -1,4 +1,4 @@
-"""Determinism tier (SURVEY.md §4; VERDICT.md round 1 item 6).
+"""Determinism tier (SURVEY.md §4; review round 1 item 6).
 
 Three guarantees, strongest first:
 

@@ -1,5 +1,5 @@
 """Mesh-distributed Cholesky + triangular inversion (ops/dist_chol.py) —
-the second distributed-factorization cut (SURVEY.md §2.2, VERDICT round 3
+the second distributed-factorization cut (SURVEY.md §2.2, review round 3
 item 6): unlike round 3's sharded-TRSM-only build, no stage may
 materialize a replicated m×m buffer on any device."""
 
@@ -122,7 +122,7 @@ def test_memory_ragged_m_stays_sharded(mesh8):
 
 
 def test_block_linking_factor_distributes_over_mesh(mesh8):
-    """VERDICT round-4 item 7: with a mesh, the block backend's
+    """review round-4 item 7: with a mesh, the block backend's
     link x link Schur factorization must route through chol_tri_inv_mesh
     (column-sharded factor) instead of replicating it on every device.
     Compile-time per-device temp peak of one f64c segment program at

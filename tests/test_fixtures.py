@@ -1,6 +1,6 @@
 """Golden MPS fixtures: exact parse checks + hand-derived optima.
 
-VERDICT.md round 1 item 7: real Netlib files are unreachable (zero
+review round 1 item 7: real Netlib files are unreachable (zero
 egress), so these vendored hand-written files carry the real-world
 quirks instead — RANGES on all three row types (incl. negative range on
 an E row), the negative-UP lower-bound quirk, MI/FX bounds, extra free N

@@ -4,7 +4,7 @@ semantics, the interprocedural v2 families (spmd-*, lock-order,
 blocking-under-lock) with their fixture twins, the static-vs-dynamic
 lock-order cross-check on a live 3-thread SolveService drain, the
 --baseline incremental diff-gate, the stdlib-only analyzer contract,
-the --require-tpu envelope guard, and the tier-1 gate — `cli check
+the no-TPU envelope guard (bench.py, require_tpu), and the tier-1 gate — `cli check
 distributedlpsolver_tpu/` must exit 0 with zero unsuppressed findings
 on the landed tree (and against the committed empty baseline)."""
 
@@ -526,10 +526,35 @@ def test_static_vs_dynamic_lock_order_cross_check(tmp_path):
 
 
 class TestEnvelopeGuard:
-    def test_require_tpu_disabled_noop(self):
-        from distributedlpsolver_tpu.utils.accel import require_tpu
+    def test_bench_refuses_cpu_before_measuring(self):
+        # No accelerator is a non-zero exit, never a switch to the CPU.
+        import os
+        import subprocess
+        import sys
 
-        require_tpu(False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        from distributedlpsolver_tpu.utils.accel import REQUIRE_TPU_EXIT
+
+        proc = subprocess.run(
+            [sys.executable, "bench.py"], cwd=repo,
+            env=dict(os.environ, JAX_PLATFORMS="cpu"),
+            capture_output=True, text=True, timeout=240,
+        )
+        assert proc.returncode == REQUIRE_TPU_EXIT, proc.stderr[-2000:]
+        assert proc.stdout.strip() == ""
+
+    def test_bench_rejects_unknown_backend(self):
+        import os
+        import subprocess
+        import sys
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        proc = subprocess.run(
+            [sys.executable, "bench.py", "--backend", "no-such-backend"],
+            cwd=repo, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+            capture_output=True, text=True, timeout=240,
+        )
+        assert proc.returncode == 2 and "no-such-backend" in proc.stderr
 
     def test_require_tpu_fails_on_cpu(self):
         # conftest pins JAX_PLATFORMS=cpu, so the guard must abort with
@@ -540,7 +565,7 @@ class TestEnvelopeGuard:
         )
 
         with pytest.raises(SystemExit) as exc:
-            require_tpu(True)
+            require_tpu()
         assert exc.value.code == REQUIRE_TPU_EXIT
 
 

@@ -294,7 +294,7 @@ class TestAdversarial:
     """Adversarial parser inputs beyond the two hand-written fixtures:
     fixed-format layout quirks, RANGES sign conventions per row type,
     duplicate entries in every section, and a writer-driven fuzz
-    round-trip (VERDICT "What's missing" #5)."""
+    round-trip (review "What's missing" #5)."""
 
     def test_fixed_format_column_layout(self):
         # Genuine fixed-column layout (fields at columns 2/5/15/25/40/50,

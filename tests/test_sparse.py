@@ -469,6 +469,27 @@ class TestRouting:
             name, hint = choose_backend_name(inf, platform)
             assert name == "sparse-iterative"
 
+    def test_hintless_huge_block_angular_routes_sparse_iterative(
+        self, monkeypatch
+    ):
+        # The huge-sparse gate fires before structure detection, on every
+        # platform: a hint-less block-angular problem past it goes to the
+        # matrix-free tier even in detect mode (the cli-solve route
+        # chip_smoke phase (b) exercises).
+        from distributedlpsolver_tpu.backends import auto
+        from distributedlpsolver_tpu.models.generators import block_angular_lp
+        from distributedlpsolver_tpu.models.problem import to_interior_form
+
+        monkeypatch.setattr(auto, "_HUGE_SPARSE_ROWS", 100)
+        p = block_angular_lp(6, 24, 48, 12, seed=3, sparse=True, density=0.15)
+        p.block_structure = None
+        inf = to_interior_form(p)
+        for platform in ("cpu", "tpu"):
+            for detect in (False, True):
+                assert auto.choose_backend_name(
+                    inf, platform, detect=detect
+                ) == ("sparse-iterative", None)
+
     def test_moderate_sparse_still_routes_cpu_sparse(self):
         # The pre-existing routing stays: sub-huge unstructured sparse
         # keeps the sparse-direct host backend.

