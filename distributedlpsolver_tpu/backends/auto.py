@@ -25,6 +25,7 @@ from distributedlpsolver_tpu.backends.base import (
 from distributedlpsolver_tpu.ipm.config import SolverConfig
 from distributedlpsolver_tpu.ipm.state import IPMState, StepStats
 from distributedlpsolver_tpu.models.problem import InteriorForm
+from distributedlpsolver_tpu.obs import trace as obs_trace
 
 # Below this many matrix entries the whole solve is cheaper than device
 # dispatch (measured before PR 21 on a remote TPU: 27×51 → ~10 ms CPU vs
@@ -174,9 +175,10 @@ class AutoBackend(SolverBackend):
         self._inner: SolverBackend | None = None
 
     def setup(self, inf: InteriorForm, config: SolverConfig) -> None:
-        name, hint = choose_backend_name(
-            inf, jax.default_backend(), detect=True
-        )
+        with obs_trace.span("lp.detect"):
+            name, hint = choose_backend_name(
+                inf, jax.default_backend(), detect=True
+            )
         if hint is not None:
             inf.block_structure = hint
         self._inner = get_backend(name)
@@ -194,6 +196,10 @@ class AutoBackend(SolverBackend):
 
     def solve_full(self, state: IPMState):
         return self._inner.solve_full(state)
+
+    @property
+    def phase_report(self):
+        return getattr(self._inner, "phase_report", None)
 
     def to_host(self, state: IPMState) -> IPMState:
         return self._inner.to_host(state)

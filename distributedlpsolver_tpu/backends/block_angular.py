@@ -1163,7 +1163,7 @@ class BlockAngularBackend(SolverBackend):
                 1 if mode == "pcg"
                 else core.seg_open(cfg.segment_iters, flops / rate)
             )
-            return (make_run_seg, window, patience_now, seg0)
+            return (make_run_seg, window, patience_now, seg0, mode)
 
         self.phase_report = []  # per-phase iters/wall split (utilization)
         st, it, status, buf, _ = core.drive_phase_plan(
@@ -1171,10 +1171,6 @@ class BlockAngularBackend(SolverBackend):
             state, jnp.asarray(self._reg, dtype), cfg.max_iter, buf_cap, dtype,
             report=self.phase_report,
         )
-        # Phase MODE from the plan spec (utilization folding keys seed
-        # rates off this; index guessing breaks on 1/2/3-phase plans).
-        for ph, spec in zip(self.phase_report, plan):
-            ph["mode"] = spec[1]
         return st, it, status, buf
 
     def solve_full(self, state: IPMState):

@@ -2018,22 +2018,19 @@ class DenseJaxBackend(SolverBackend):
             # 32-iteration opening PCG segment crashed the worker). Open with ONE iteration and let the
             # measured-rate adaptation in drive_segments size the rest.
             seg0 = 1 if cgi else core.seg_open(seg_cfg, est)
-            return (make_run_seg, window, patience, seg0)
+            # Phase MODE from the plan spec itself (cg_iters > 0 = pcg,
+            # else the factor dtype) — utilization folding keys seed
+            # rates off this, never off positional index guesses.
+            mode = "pcg" if cgi else ("f32" if fdt == "float32" else "f64")
+            return (make_run_seg, window, patience, seg0, mode)
 
+        # `plan`'s specs hold the ~2 GB Pallas-padded A32 and the closure
+        # factor: drop it before the solve. Holding it across the endgame
+        # kept those buffers alive through _endgame_loop's entry-time
+        # release and OOMed the projector's AAᵀ assembly at 10k×50k
+        # (observed 2026-07-31 — the same +2.4 GB failure the release
+        # exists to prevent).
         plan = self._phase_plan()
-        # Phase MODE from the plan spec itself (cg_iters > 0 = pcg, else
-        # the factor dtype) — utilization folding keys seed rates off
-        # this, never off positional index guesses. Extracted BEFORE the
-        # solve so `plan` (whose specs hold the ~2 GB Pallas-padded A32
-        # and the closure factor) can be dropped: holding it across the
-        # endgame kept those buffers alive through _endgame_loop's
-        # entry-time release and OOMed the projector's AAᵀ assembly at
-        # 10k×50k (observed 2026-07-31 — the same +2.4 GB failure the
-        # release exists to prevent).
-        modes = [
-            "pcg" if spec[7] else ("f32" if spec[1] == "float32" else "f64")
-            for spec in plan
-        ]
         phases_built = [make_phase(s) for s in plan]
         del plan
         self.phase_report = []  # per-phase iters/wall split (utilization)
@@ -2043,8 +2040,6 @@ class DenseJaxBackend(SolverBackend):
             report=self.phase_report,
         )
         del phases_built  # make_phase closures also reference A32
-        for ph, mode in zip(self.phase_report, modes):
-            ph["mode"] = mode
         m, n = self._A.shape
         # OPTIMAL re-enters the endgame ONLY when the two-phase plan
         # actually clamped the PCG phase to the looser handoff tol — then

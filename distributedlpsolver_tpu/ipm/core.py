@@ -37,6 +37,7 @@ from typing import Any, Callable, NamedTuple
 
 from distributedlpsolver_tpu.ipm.config import StepParams
 from distributedlpsolver_tpu.ipm.state import IPMState, StepStats
+from distributedlpsolver_tpu.obs import trace as obs_trace
 
 
 class LinOps(NamedTuple):
@@ -768,8 +769,9 @@ def drive_segments(
         prev_it = it
         stop = min(it + seg, max_iter)
         t0 = _time.perf_counter()
-        carry, meta = run_seg(carry, stop)
-        meta = _np.asarray(meta)  # blocks; the segment's one host read
+        with obs_trace.span("ipm.segment", it=it, stop=stop):
+            carry, meta = run_seg(carry, stop)
+            meta = _np.asarray(meta)  # blocks; the segment's one host read
         dt = _time.perf_counter() - t0
         it, status = int(meta[0]), int(meta[1])
         best_err, since = float(meta[2]), int(meta[3])
@@ -864,9 +866,11 @@ def drive_phase_plan(phases, state, reg0, max_iter, buf_cap, dtype,
     """Host driver for a multi-phase segmented fused solve.
 
     ``phases`` is a list of ``(make_run_seg, stall_window,
-    stall_patience_floor, seg_init)`` where ``make_run_seg(bound) ->
+    stall_patience_floor, seg_init, mode)`` where ``make_run_seg(bound) ->
     run_seg(carry, it_stop)`` builds the phase's device program around its
-    global iteration bound. Each phase gets its own ``max_iter`` budget;
+    global iteration bound and ``mode`` names its arithmetic ("f32",
+    "mixed", "f64", "f64c", "pcg"). Each phase runs in an ``ipm.phase``
+    span. Each phase gets its own ``max_iter`` budget;
     between phases the carry is reset via :func:`segment_phase_reset`.
     Returns ``(state, iterations, status, stats_buffer, reg)`` — ``reg``
     is the final phase's escalated regularization (still on device), so
@@ -876,10 +880,10 @@ def drive_phase_plan(phases, state, reg0, max_iter, buf_cap, dtype,
     implementation shared by the dense and block backends so their
     termination semantics can never diverge.
 
-    ``report`` (optional mutable list) receives one ``{"phase", "iters",
-    "wall_s"}`` row per phase — the per-phase split the utilization
-    artifacts record (review round 3 item 4), measured here because only
-    the driver knows the phase boundaries.
+    ``report`` (optional mutable list) receives one ``{"phase", "mode",
+    "iters", "wall_s", "segments"}`` row per phase — the per-phase split
+    the utilization artifacts and ``IPMResult.phase_report`` record,
+    measured here because only the driver knows the phase boundaries.
     """
     import time as _time
 
@@ -889,17 +893,28 @@ def drive_phase_plan(phases, state, reg0, max_iter, buf_cap, dtype,
     it, status = 0, STATUS_RUNNING
     window, patience, bound = 0, 0.0, max_iter
     best, since = float("inf"), 0
-    for pi, (make_run_seg, window, patience, seg_init) in enumerate(phases):
+    for pi, (make_run_seg, window, patience, seg_init, mode) in enumerate(
+        phases
+    ):
         bound = it + max_iter
         it_before, t_ph = it, _time.perf_counter()
-        carry, (it, status, best, since) = drive_segments(
-            make_run_seg(bound), carry, bound, window, seg_init,
-            stall_patience_floor=patience, it0_status0=(it, status),
-        )
+        run_seg, segments = make_run_seg(bound), 0
+
+        def counted_seg(carry, stop, run_seg=run_seg):
+            nonlocal segments
+            segments += 1
+            return run_seg(carry, stop)
+
+        with obs_trace.span("ipm.phase", phase=pi, mode=mode):
+            carry, (it, status, best, since) = drive_segments(
+                counted_seg, carry, bound, window, seg_init,
+                stall_patience_floor=patience, it0_status0=(it, status),
+            )
         if report is not None:
             report.append({
-                "phase": pi, "iters": int(it - it_before),
+                "phase": pi, "mode": mode, "iters": int(it - it_before),
                 "wall_s": round(_time.perf_counter() - t_ph, 3),
+                "segments": segments,
             })
         if pi < len(phases) - 1:
             carry = segment_phase_reset(carry, reg0)

@@ -92,135 +92,173 @@ def solve(
     (delta-solve amortization — presolve is skipped on this path, since
     cached iterates live in the unreduced space), and stores its own
     scaling + final iterate back on an OPTIMAL finish.
-    """
-    from distributedlpsolver_tpu.backends.base import get_backend
-    from distributedlpsolver_tpu.ipm import warm as warm_mod
 
+    Each stage of the call runs in an ``obs.trace.span`` (``lp.solve``
+    around all of it); ``IPMResult.spans`` holds their self seconds.
+    ``profile_dir`` wraps the whole call in ``jax.profiler.trace``, so
+    the stages sit on the device's clock in the ``.xplane.pb`` it writes.
+    """
     cfg = config or SolverConfig()
     if config_overrides:
         cfg = cfg.replace(**config_overrides)
+    with contextlib.ExitStack() as stack:
+        if cfg.profile_dir:
+            import jax
 
-    original: Optional[LPProblem] = problem if isinstance(problem, LPProblem) else None
-    cache_fp = None
-    cache_entry = None
-    if warm_cache is not None and original is not None:
-        from distributedlpsolver_tpu.utils.fingerprint import (
-            structural_fingerprint,
+            stack.enter_context(jax.profiler.trace(cfg.profile_dir))
+        with obs_trace.collect_spans() as spans:
+            t0 = time.perf_counter()
+            with obs_trace.span("lp.solve"):
+                result = _solve(
+                    problem, backend, cfg, warm_start, hooks, warm_cache
+                )
+            t1 = time.perf_counter()
+    result.spans = spans
+    obs_trace.record_solve(t0, t1, spans, result.phase_report)
+    return result
+
+
+def _solve(problem, backend, cfg, warm_start, hooks, warm_cache):
+    from distributedlpsolver_tpu.backends.base import get_backend
+    from distributedlpsolver_tpu.ipm import warm as warm_mod
+
+    with obs_trace.span("lp.prepare"):
+        original: Optional[LPProblem] = (
+            problem if isinstance(problem, LPProblem) else None
         )
+        cache_fp = None
+        cache_entry = None
+        if warm_cache is not None and original is not None:
+            from distributedlpsolver_tpu.utils.fingerprint import (
+                structural_fingerprint,
+            )
 
-        # Model identity of the RAW problem; the entry's shape guard
-        # runs against the interior form below (cached iterates live in
-        # interior space, whose dims differ for general-form inputs).
-        cache_fp = structural_fingerprint(
-            original.A, original.m, original.n, original.lb, original.ub
-        )
-    presolve_info = None
-    if (
-        cfg.presolve
-        and original is not None
-        and original.block_structure is None  # reductions break the hint
-        and warm_start is None  # warm starts are in the unreduced space
-        and cache_fp is None  # cached iterates/scalings are too
-    ):
-        from distributedlpsolver_tpu.models.presolve import presolve as _presolve
-
-        reduced, presolve_info = _presolve(original)
-        if presolve_info.status is not None:
-            return _presolved_result(original, presolve_info, backend)
-        inf = to_interior_form(reduced)
-    else:
-        inf = to_interior_form(problem) if isinstance(problem, LPProblem) else problem
-    if cache_fp is not None:
-        cache_entry = warm_cache.lookup(cache_fp, inf.m, inf.n)
+            # Model identity of the RAW problem; the entry's shape guard
+            # runs against the interior form below (cached iterates live in
+            # interior space, whose dims differ for general-form inputs).
+            cache_fp = structural_fingerprint(
+                original.A, original.m, original.n, original.lb, original.ub
+            )
+        presolve_info = None
         if (
-            warm_start is None
-            and cache_entry is not None
-            and cache_entry.state is not None
+            cfg.presolve
+            and original is not None
+            and original.block_structure is None  # reductions break the hint
+            and warm_start is None  # warm starts are in the unreduced space
+            and cache_fp is None  # cached iterates/scalings are too
         ):
-            warm_start = warm_mod.WarmStart(cache_entry.state, source="cache")
-    if (
-        cache_entry is not None
-        and cache_entry.structure is not None
-        and inf.block_structure is None
-    ):
-        # Structure detection amortized across the stream: the hint a
-        # prior same-structure solve recorded routes this one straight
-        # to the block backend without re-detecting.
-        inf.block_structure = cache_entry.structure
+            from distributedlpsolver_tpu.models.presolve import (
+                presolve as _presolve,
+            )
 
-    scaling = None
-    inf_solve = inf
-    if cfg.scale:
+            reduced, presolve_info = _presolve(original)
+            if presolve_info.status is not None:
+                return _presolved_result(original, presolve_info, backend)
+            inf = to_interior_form(reduced)
+        else:
+            inf = (
+                to_interior_form(problem)
+                if isinstance(problem, LPProblem) else problem
+            )
+        if cache_fp is not None:
+            cache_entry = warm_cache.lookup(cache_fp, inf.m, inf.n)
+            if (
+                warm_start is None
+                and cache_entry is not None
+                and cache_entry.state is not None
+            ):
+                warm_start = warm_mod.WarmStart(
+                    cache_entry.state, source="cache"
+                )
         if (
             cache_entry is not None
-            and cache_entry.scaling is not None
-            and cache_entry.scaled_A is not None
+            and cache_entry.structure is not None
+            and inf.block_structure is None
         ):
-            # Delta-solve amortization: Ruiz factors depend only on A,
-            # so a same-structure request reuses the cached (Dr, Dc) and
-            # pre-scaled A — only the new b/c/u are rescaled here.
-            scaling = cache_entry.scaling
-            inf_solve = _rescale_interior(inf, scaling, cache_entry.scaled_A)
-        else:
-            from distributedlpsolver_tpu.models.scaling import equilibrate
+            # Structure detection amortized across the stream: the hint a
+            # prior same-structure solve recorded routes this one straight
+            # to the block backend without re-detecting.
+            inf.block_structure = cache_entry.structure
 
-            inf_solve, scaling = equilibrate(inf)
+        scaling = None
+        inf_solve = inf
+        if cfg.scale:
+            if (
+                cache_entry is not None
+                and cache_entry.scaling is not None
+                and cache_entry.scaled_A is not None
+            ):
+                # Delta-solve amortization: Ruiz factors depend only on A,
+                # so a same-structure request reuses the cached (Dr, Dc) and
+                # pre-scaled A — only the new b/c/u are rescaled here.
+                scaling = cache_entry.scaling
+                inf_solve = _rescale_interior(
+                    inf, scaling, cache_entry.scaled_A
+                )
+            else:
+                from distributedlpsolver_tpu.models.scaling import equilibrate
 
-    be = get_backend(backend) if isinstance(backend, str) else backend
+                inf_solve, scaling = equilibrate(inf)
+
     logger = IterLogger(
         cfg.verbose, cfg.log_jsonl, fsync=cfg.log_fsync, append=cfg.log_append
     )
+    with obs_trace.span("lp.build"):
+        be = get_backend(backend) if isinstance(backend, str) else backend
+        t_setup0 = time.perf_counter()
+        be.setup(inf_solve, cfg)
 
     def to_solver_space(host_state):
         return be.from_host(
             scaling.scale_state(host_state) if scaling else host_state
         )
 
-    t_setup0 = time.perf_counter()
-    be.setup(inf_solve, cfg)
-    # Warm-cache-supplied preconditioner (the PR 8 follow-on): a backend
-    # with the offer/export seam (sparse-iterative) seeds its PCG
-    # preconditioner from the cached final scaling of the last OPTIMAL
-    # same-structure solve — the factors freeze for the early (loose-
-    # forcing) iterations instead of refactoring every step. Only valid
-    # when this solve reuses the SAME Ruiz scaling the cached d was
-    # exported under (the delta-solve path); offer_precond shape-guards
-    # the rest.
-    if (
-        cache_entry is not None
-        and cache_entry.precond_d is not None
-        and hasattr(be, "offer_precond")
-        and (not cfg.scale or cache_entry.scaling is not None)
-    ):
-        be.offer_precond(cache_entry.precond_d)
-    fingerprint = ckpt.problem_fingerprint(inf) if cfg.checkpoint_path else ""
-    resumed = (
-        ckpt.maybe_load(cfg.checkpoint_path, fingerprint)
-        if warm_start is None
-        else None
-    )
-    warm_label = "cold"
-    if isinstance(warm_start, warm_mod.WarmStart):
-        state, warm_label = _init_warm_start(
-            be, warm_start, inf, inf_solve, scaling, to_solver_space
+    with obs_trace.span("lp.start"):
+        # Warm-cache-supplied preconditioner: a backend
+        # with the offer/export seam (sparse-iterative) seeds its PCG
+        # preconditioner from the cached final scaling of the last OPTIMAL
+        # same-structure solve — the factors freeze for the early (loose-
+        # forcing) iterations instead of refactoring every step. Only valid
+        # when this solve reuses the SAME Ruiz scaling the cached d was
+        # exported under (the delta-solve path); offer_precond shape-guards
+        # the rest.
+        if (
+            cache_entry is not None
+            and cache_entry.precond_d is not None
+            and hasattr(be, "offer_precond")
+            and (not cfg.scale or cache_entry.scaling is not None)
+        ):
+            be.offer_precond(cache_entry.precond_d)
+        fingerprint = (
+            ckpt.problem_fingerprint(inf) if cfg.checkpoint_path else ""
         )
-        start_iter = 0
-    elif warm_start is not None:
-        state, start_iter = to_solver_space(warm_start), 0
-    elif (
-        resumed is not None
-        and resumed[2] == inf.name
-        and resumed[0].x.shape == (inf.n,)
-        and resumed[0].y.shape == (inf.m,)
-    ):
-        # Checkpoints are host-canonical (utils/checkpoint.py v3):
-        # to_solver_space → backend.from_host re-pads and re-places the
-        # iterate for THIS backend's layout, so the same file resumes on
-        # a different mesh size (the elastic shrink path), a single
-        # device, or the CPU.
-        state, start_iter = to_solver_space(resumed[0]), resumed[1]
-    else:
-        state, start_iter = be.starting_point(), 0
+        resumed = (
+            ckpt.maybe_load(cfg.checkpoint_path, fingerprint)
+            if warm_start is None
+            else None
+        )
+        warm_label = "cold"
+        if isinstance(warm_start, warm_mod.WarmStart):
+            state, warm_label = _init_warm_start(
+                be, warm_start, inf, inf_solve, scaling, to_solver_space
+            )
+            start_iter = 0
+        elif warm_start is not None:
+            state, start_iter = to_solver_space(warm_start), 0
+        elif (
+            resumed is not None
+            and resumed[2] == inf.name
+            and resumed[0].x.shape == (inf.n,)
+            and resumed[0].y.shape == (inf.m,)
+        ):
+            # Checkpoints are host-canonical (utils/checkpoint.py v3):
+            # to_solver_space → backend.from_host re-pads and re-places the
+            # iterate for THIS backend's layout, so the same file resumes on
+            # a different mesh size (the elastic shrink path), a single
+            # device, or the CPU.
+            state, start_iter = to_solver_space(resumed[0]), resumed[1]
+        else:
+            state, start_iter = be.starting_point(), 0
     setup_time = time.perf_counter() - t_setup0
 
     on_host_state = None
@@ -246,22 +284,24 @@ def solve(
         use_fused = not (cfg.checkpoint_every and cfg.checkpoint_path)
     if hooks is not None:
         use_fused = False  # hooks need iteration boundaries on the host
-    if cfg.profile_dir:
-        # Profiling wants per-iteration dispatch boundaries: the fused
-        # loop is one opaque device program (and the profiler context
-        # only wraps the host loop), so --profile-dir silently produced
-        # nothing whenever the fused path ran.
-        use_fused = False
     if use_fused:
-        fused = _try_fused(be, state, cfg, logger)
+        # A reused backend instance still holds its last solve's report;
+        # only a report this loop wrote (a new list) belongs to this solve.
+        report0 = getattr(be, "phase_report", None)
+        with obs_trace.span("ipm.loop"):
+            fused = _try_fused(be, state, cfg, logger)
         if fused is not None:
             state, status, history, last, solve_time, fused_iters = fused
-            return _finalize(
-                be, state, status, history, last, solve_time, setup_time,
-                inf, original, backend, start_iter, scaling=scaling,
-                presolve_info=presolve_info, extra_iters=fused_iters,
-                warm_label=warm_label, on_host_state=on_host_state,
-            )
+            report = getattr(be, "phase_report", None)
+            with obs_trace.span("lp.finalize"):
+                return _finalize(
+                    be, state, status, history, last, solve_time,
+                    setup_time, inf, original, backend, start_iter,
+                    scaling=scaling, presolve_info=presolve_info,
+                    extra_iters=fused_iters, warm_label=warm_label,
+                    on_host_state=on_host_state,
+                    phase_report=None if report is report0 else report,
+                )
 
     status = Status.ITERATION_LIMIT
     history = []
@@ -293,9 +333,9 @@ def solve(
         else None
     )
     t_solve0 = time.perf_counter()
-    profile_stack = contextlib.ExitStack()
+    loop = contextlib.ExitStack()
     try:
-        profile_stack.enter_context(_maybe_profiler(cfg.profile_dir))
+        loop.enter_context(obs_trace.span("ipm.loop"))
         while it < cfg.max_iter:
             t_it0 = time.perf_counter()
             refactor = 0
@@ -368,20 +408,17 @@ def solve(
                 status = Status.NUMERICAL_ERROR
                 break
     finally:
-        profile_stack.close()
+        loop.close()
         solve_time = time.perf_counter() - t_solve0
         logger.close()
-        if cfg.profile_dir:
-            _write_profile_report(
-                cfg.profile_dir, history, setup_time, solve_time
-            )
 
-    return _finalize(
-        be, state, status, history, last, solve_time, setup_time,
-        inf, original, backend, start_iter, extra_iters=it - start_iter,
-        scaling=scaling, presolve_info=presolve_info,
-        warm_label=warm_label, on_host_state=on_host_state,
-    )
+    with obs_trace.span("lp.finalize"):
+        return _finalize(
+            be, state, status, history, last, solve_time, setup_time,
+            inf, original, backend, start_iter, extra_iters=it - start_iter,
+            scaling=scaling, presolve_info=presolve_info,
+            warm_label=warm_label, on_host_state=on_host_state,
+        )
 
 
 def _rescale_interior(inf: InteriorForm, scaling, scaled_A) -> InteriorForm:
@@ -510,6 +547,7 @@ def _finalize(
     be, state, status, history, last, solve_time, setup_time,
     inf, original, backend, start_iter, extra_iters=None, scaling=None,
     presolve_info=None, warm_label="cold", on_host_state=None,
+    phase_report=None,
 ):
     n_iters = extra_iters if extra_iters is not None else len(history)
     _reg = obs_metrics.get_registry()
@@ -636,6 +674,7 @@ def _finalize(
         s=s,
         certificate=certificate,
         warm=warm_label,
+        phase_report=phase_report,
     )
 
 
@@ -674,64 +713,3 @@ def _presolved_result(original: LPProblem, info, backend) -> IPMResult:
 def _to_floats(stats):
     d = {f: float(np.asarray(getattr(stats, f))) for f in stats._fields if f != "bad"}
     return d
-
-
-def _maybe_profiler(profile_dir: Optional[str]):
-    if profile_dir:
-        import jax
-
-        return jax.profiler.trace(profile_dir)
-    import contextlib
-
-    return contextlib.nullcontext()
-
-
-def _write_profile_report(
-    profile_dir: str, history, setup_time: float, solve_time: float
-) -> None:
-    """--profile-dir honesty (review §5.1): always write the
-    dispatch-level timer's report (the per-iteration wall times the host
-    loop measures anyway) into the profile dir next to whatever
-    ``jax.profiler.trace`` wrote, record whether the trace wrote any file,
-    and WARN when it wrote none, so nobody mistakes an empty trace for a
-    profiled run. (The remote chip used before PR 21 returned empty
-    traces; chip_smoke.py reports whether a local TPU writes one.)"""
-    import json
-    import os
-    import sys
-
-    report_name = "dispatch_timings.json"
-    try:
-        os.makedirs(profile_dir, exist_ok=True)
-        traced = any(
-            fn != report_name
-            for _, _, files in os.walk(profile_dir)
-            for fn in files
-        )
-        t_iters = [r.t_iter for r in history]
-        report = {
-            "jax_profiler_trace_wrote_files": traced,
-            "setup_s": round(setup_time, 6),
-            "solve_s": round(solve_time, 6),
-            "iterations": len(t_iters),
-            "t_iter_s": [round(t, 6) for t in t_iters],
-            "t_iter_mean_s": round(
-                sum(t_iters) / len(t_iters), 6
-            ) if t_iters else None,
-            "t_iter_max_s": round(max(t_iters), 6) if t_iters else None,
-        }
-        with open(os.path.join(profile_dir, report_name), "w") as fh:
-            json.dump(report, fh, indent=2)
-        if not traced:
-            print(
-                f"WARNING: jax.profiler.trace produced no files in "
-                f"{profile_dir!r}; wrote the "
-                f"dispatch-level timing report to {report_name} instead",
-                file=sys.stderr,
-            )
-    except Exception as e:  # profiling must never sink the solve
-        print(
-            f"WARNING: could not write profile report to "
-            f"{profile_dir!r}: {e}",
-            file=sys.stderr,
-        )

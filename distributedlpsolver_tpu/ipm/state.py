@@ -160,6 +160,15 @@ class IPMResult:
     # WarmStart was offered but its initial residuals regressed past the
     # safeguard and the solve fell back to the cold start). See ipm/warm.
     warm: str = "cold"
+    # Self seconds by stage span (obs/trace.span: lp.solve, lp.prepare,
+    # lp.build, lp.detect, lp.start, ipm.loop, ipm.phase, ipm.segment,
+    # lp.finalize), summed over the solve: the values add up to the
+    # whole call's time, and lp.solve's own entry is what no stage covers.
+    spans: dict = dataclasses.field(default_factory=dict)
+    # One row per phase of a segmented fused solve ({"phase", "mode",
+    # "iters", "wall_s", "segments"}, core.drive_phase_plan); None where
+    # the loop ran no phase plan.
+    phase_report: Optional[list] = None
 
     @property
     def iters_per_sec(self) -> float:

@@ -24,10 +24,17 @@ Like the metrics registry, the module default is :data:`NULL_TRACER`,
 whose methods are no-ops — instrumentation sites call unconditionally
 and the disabled path allocates nothing. The real tracer takes one lock
 per event append; it is never on the device path.
+
+:func:`span` is the one primitive for the stages of a solve. It writes
+each stage onto three sinks at once: the host plane of an active
+``jax.profiler`` trace (the device's clock), the installed tracer's
+``X`` events, and the calling thread's :func:`collect_spans` table of
+self seconds, which ``ipm.solve`` turns into ``IPMResult.spans``.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import threading
@@ -234,3 +241,86 @@ def set_tracer(tracer) -> object:
     """Install ``tracer`` as the module default (None restores the no-op
     tracer); returns the previous default for scoped restore."""
     return _DEFAULT.set(tracer)
+
+
+# --------------------------------------------------------------------------
+# Stage spans: profiler annotation + tracer event + per-solve self time
+# --------------------------------------------------------------------------
+
+_tls = threading.local()
+
+
+class _SpanTable:
+    """Self seconds by span name for one thread's spans. ``_open`` holds,
+    for each span still open, the seconds its closed children took."""
+
+    __slots__ = ("seconds", "_open")
+
+    def __init__(self):
+        self.seconds: dict = {}
+        self._open: list = []
+
+    def open(self) -> None:
+        self._open.append(0.0)
+
+    def close(self, name: str, dur: float) -> None:
+        child = self._open.pop()
+        self.seconds[name] = self.seconds.get(name, 0.0) + dur - child
+        if self._open:
+            self._open[-1] += dur
+
+
+@contextlib.contextmanager
+def collect_spans() -> Iterator[dict]:
+    """Install a span table on the calling thread for the block; yields
+    its ``{name: self seconds}`` dict. Spans on other threads never land
+    in it, so concurrent solves keep apart. An inner ``collect_spans``
+    shadows the outer one until it exits."""
+    prev = getattr(_tls, "table", None)
+    table = _tls.table = _SpanTable()
+    try:
+        yield table.seconds
+    finally:
+        _tls.table = prev
+
+
+@contextlib.contextmanager
+def span(name: str, **args) -> Iterator[None]:
+    """One stage of work on the calling thread: a
+    ``jax.profiler.TraceAnnotation`` (``args`` become its stats), an
+    ``X`` event on the installed tracer when it is enabled, and the
+    span's self time (its duration less its nested spans') in the
+    thread's :func:`collect_spans` table, if one is installed."""
+    import jax  # lazily: ipm.core keeps jax out of its import path
+
+    table = getattr(_tls, "table", None)
+    with jax.profiler.TraceAnnotation(name, **args), get_tracer().span(
+        name, cat="stage", args=args or None
+    ):
+        if table is None:
+            yield
+            return
+        table.open()
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            table.close(name, time.perf_counter() - t0)
+
+
+# The span tables and phase reports of this process's last solves, for a
+# caller that did not keep the IPMResult (a benchmark reading stage and
+# phase times after its window): one dict per solve, ``{"t0", "t1",
+# "spans", "phase_report"}``, t0/t1 on the perf_counter clock. Bounded;
+# appends are atomic.
+_RECENT_SOLVES: collections.deque = collections.deque(maxlen=64)
+
+
+def record_solve(t0: float, t1: float, spans: dict, phase_report) -> None:
+    _RECENT_SOLVES.append({"t0": t0, "t1": t1, "spans": dict(spans),
+                           "phase_report": phase_report})
+
+
+def recent_solves() -> list:
+    """The last solves' span records, oldest first (see ``record_solve``)."""
+    return list(_RECENT_SOLVES)

@@ -2364,8 +2364,7 @@ class SolveService:
 
     def dispatch_report(self) -> List[dict]:
         """Per-dispatch timing rows (pack/compile/solve/overlap ms, mesh
-        width) — the serving analogue of the driver's dispatch_timings
-        report; bounded to the most recent 2048 dispatches."""
+        width), bounded to the most recent 2048 dispatches."""
         with self._lock:
             return list(self._dispatch_rows)
 

@@ -1,8 +1,9 @@
 """Utilization folding for phase reports (review round 3 item 4).
 
-`core.drive_phase_plan` records one ``{"phase", "iters", "wall_s"}`` row
-per phase; the backends stamp each row's ``"mode"`` from their own plan
-specs ("f32"/"mixed"/"f64"/"f64c"/"pcg"/"endgame"). This helper turns
+`core.drive_phase_plan` records one ``{"phase", "mode", "iters",
+"wall_s", "segments"}`` row per phase, its mode from the backend's own
+plan spec ("f32"/"mixed"/"f64"/"f64c"/"pcg"; the dense endgame appends
+an "endgame" row). This helper turns
 that into the utilization fields the scale artifacts record: effective
 FLOP/s per assembly-bound phase, its percentage of the watchdog seed
 rates (`core.SEG_RATE_F32`/`SEG_RATE_F64` — the conservative per-dtype

@@ -167,34 +167,54 @@ def test_compile_cache_dir_rule(tmp_path, from_env):
     assert out == want
 
 
-def test_profile_dir_always_yields_a_report(tmp_path):
-    """--profile-dir honesty: whatever jax.profiler.trace writes, the
-    profile dir must come back with
-    the dispatch-level timing report, one entry per iteration."""
+def _profiled_dense_solve(tmp_path):
+    """A fused-loop solve (the dense ``tpu`` backend, on the CPU platform)
+    under ``profile_dir``; returns (result, solve_full returned a loop,
+    host event names of the written trace)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from distributedlpsolver_tpu.backends.dense import DenseJaxBackend
+    from distributedlpsolver_tpu.models.generators import random_dense_lp
+
+    be = DenseJaxBackend()
+    fused = []
+    solve_full = be.solve_full
+
+    def spy(state):
+        out = solve_full(state)
+        fused.append(out is not None)
+        return out
+
+    be.solve_full = spy
     prof = tmp_path / "prof"
-    p = random_general_lp(8, 18, seed=4)
-    r = solve(p, backend="cpu", profile_dir=str(prof), verbose=False)
+    r = solve(random_dense_lp(16, 40, seed=3), backend=be,
+              profile_dir=str(prof), verbose=False)
+    files = glob.glob(str(prof / "**" / "*.xplane.pb"), recursive=True)
+    assert len(files) == 1
+    names = {
+        ev.name
+        for plane in ProfileData.from_file(files[0]).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+    }
+    return r, fused, names
+
+
+def test_profile_dir_traces_every_stage_of_the_solve(tmp_path):
+    r, _, names = _profiled_dense_solve(tmp_path)
     assert r.status == Status.OPTIMAL
-    report = json.loads((prof / "dispatch_timings.json").read_text())
-    assert report["iterations"] == r.iterations > 0
-    assert len(report["t_iter_s"]) == r.iterations
-    assert report["solve_s"] > 0
-    assert "jax_profiler_trace_wrote_files" in report
+    assert {"lp.solve", "lp.prepare", "lp.build", "lp.start", "ipm.loop",
+            "lp.finalize"} <= names
 
 
-def test_profile_dir_forces_host_loop(tmp_path):
-    """The fused on-device loop has no iteration boundaries to profile;
-    profile_dir must force the per-iteration host driver (else the trace
-    wraps nothing and the report has no rows)."""
-    prof = tmp_path / "prof2"
-    p = random_general_lp(8, 18, seed=4)
-    r = solve(
-        p, backend="cpu", profile_dir=str(prof), fused_loop=None,
-        verbose=False,
-    )
-    report = json.loads((prof / "dispatch_timings.json").read_text())
-    # host loop ran: true per-iteration wall times, not one fused average
-    assert len(set(report["t_iter_s"])) > 1 or r.iterations <= 1
+def test_profile_dir_keeps_the_fused_loop(tmp_path):
+    r, fused, _ = _profiled_dense_solve(tmp_path)
+    assert fused == [True]
+    # One device program: every history row carries the loop's average.
+    assert len({rec.t_iter for rec in r.history}) == 1
 
 
 def test_native_object_keyed_by_source_flags_and_cpu(monkeypatch):

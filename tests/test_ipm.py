@@ -180,7 +180,7 @@ def test_drive_phase_plan_status_mapping():
     state = jnp.zeros(3)
     reg0 = jnp.asarray(1e-10, jnp.float64)
     buf_cap = 8
-    phases = [(make_run_seg, 0, 0.0, 2)]
+    phases = [(make_run_seg, 0, 0.0, 2, "f64")]
     st, it, status, buf, reg_out = core.drive_phase_plan(
         phases, state, reg0, 20, buf_cap, jnp.float64
     )
@@ -200,7 +200,7 @@ def test_drive_phase_plan_status_mapping():
         return run_seg
 
     st, it, status, buf, _ = core.drive_phase_plan(
-        [(make_run_seg2, 0, 0.0, 4)], state, reg0, 12, buf_cap, jnp.float64
+        [(make_run_seg2, 0, 0.0, 4, "f64")], state, reg0, 12, buf_cap, jnp.float64
     )
     assert int(status) == core.STATUS_MAXITER and it == 12
 
