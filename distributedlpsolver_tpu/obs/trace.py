@@ -294,18 +294,37 @@ def span(name: str, **args) -> Iterator[None]:
     import jax  # lazily: ipm.core keeps jax out of its import path
 
     table = getattr(_tls, "table", None)
-    with jax.profiler.TraceAnnotation(name, **args), get_tracer().span(
-        name, cat="stage", args=args or None
+    stack = _tls.__dict__.setdefault("open_spans", [])
+    with jax.profiler.TraceAnnotation(name, **args) as ann, get_tracer().span(
+        name, cat="stage", args=args
     ):
-        if table is None:
-            yield
-            return
-        table.open()
-        t0 = time.perf_counter()
+        stack.append((name, ann, args))
         try:
-            yield
+            if table is None:
+                yield
+                return
+            table.open()
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                table.close(name, time.perf_counter() - t0)
         finally:
-            table.close(name, time.perf_counter() - t0)
+            stack.pop()
+
+
+def annotate(name: str, **args) -> None:
+    """Add ``args`` to the innermost open :func:`span` called ``name`` on
+    the calling thread — for what is known only once the span's work has
+    run (the profiler event's stats and the tracer event's args both get
+    them). No such span open: nothing happens."""
+    for open_name, ann, open_args in reversed(
+        getattr(_tls, "open_spans", ())
+    ):
+        if open_name == name:
+            ann.set_metadata(**args)
+            open_args.update(args)
+            return
 
 
 # The span tables and phase reports of this process's last solves, for a

@@ -47,7 +47,9 @@ def fold_utilization(report, flops_per_iter: float, device_kind=None):
     ``flops_per_iter`` is the backend's own per-iteration estimate for
     the direct factorization path (e.g. ``BlockAngularBackend._f64_flops``)
     — the same operation count runs in f32 and f64, only the rates
-    differ.
+    differ. A row that carries its own ``flops_per_iter`` (the block
+    backend's rows: its f64c phase may assemble from nonzero pairs while
+    the f32 phase runs the dense einsums) is folded with that instead.
     """
     from distributedlpsolver_tpu.ipm import core
 
@@ -66,7 +68,8 @@ def fold_utilization(report, flops_per_iter: float, device_kind=None):
         pair = rates.get(ph.get("mode"))
         if pair and ph.get("iters") and ph.get("wall_s", 0) > 0:
             seed, peak_key = pair
-            eff = flops_per_iter * ph["iters"] / ph["wall_s"]
+            flops = ph.get("flops_per_iter", flops_per_iter)
+            eff = flops * ph["iters"] / ph["wall_s"]
             ph["eff_flops_per_s"] = f"{eff:.3g}"
             ph["pct_of_seed_rate"] = round(100.0 * eff / seed, 1)
             if peaks is not None:

@@ -263,3 +263,217 @@ def test_kgroup_factorize_solve_match_oneshot(monkeypatch):
 
     np.testing.assert_allclose(x_g, x_ref, rtol=1e-12, atol=1e-12)
     np.testing.assert_allclose(xc_g, xc_ref, rtol=1e-12, atol=1e-12)
+
+
+def _network_lp(K, nodes, extra, joint, seed):
+    """pds-shaped multicommodity flow: K copies of one node-arc incidence
+    block (a cycle through every node, ``extra`` random arcs, a supply
+    arc into every fourth node) and ``joint`` capacity rows, each one +1
+    per commodity on one arc. Feasible and bounded by construction."""
+    import scipy.sparse as sp
+
+    from distributedlpsolver_tpu.models.problem import LPProblem
+
+    rng = np.random.default_rng(seed)
+    cyc = rng.permutation(nodes)
+    tail = np.r_[cyc, rng.integers(0, nodes, extra)]
+    head = np.r_[np.roll(cyc, -1),
+                 (tail[nodes:] + rng.integers(1, nodes, extra)) % nodes]
+    sup = np.arange(0, nodes, 4)
+    arcs = len(tail)
+    E = arcs + len(sup)
+    r1 = np.r_[tail, head, sup]
+    c1 = np.r_[np.arange(arcs), np.arange(arcs), arcs + np.arange(len(sup))]
+    v1 = np.r_[-np.ones(arcs), np.ones(arcs + len(sup))]
+    cap = rng.choice(arcs, joint, replace=False)
+    rows = [r1 + k * nodes for k in range(K)]
+    rows.append(np.repeat(K * nodes + np.arange(joint), K))
+    cols = [c1 + k * E for k in range(K)]
+    cols.append((cap[:, None] + E * np.arange(K)[None, :]).ravel())
+    vals = [v1] * K + [np.ones(joint * K)]
+    m, n = K * nodes + joint, K * E
+    A = sp.csr_matrix(
+        (np.concatenate(vals), (np.concatenate(rows), np.concatenate(cols))),
+        shape=(m, n))
+    x0 = rng.uniform(0.5, 2.0, n)
+    Ax0 = A @ x0
+    y0 = rng.standard_normal(m)
+    y0[K * nodes:] = -np.abs(y0[K * nodes:])
+    p = LPProblem(
+        c=A.T @ y0 + rng.uniform(0.5, 2.0, n), A=A,
+        rlb=np.r_[Ax0[:K * nodes], np.full(joint, -np.inf)],
+        rub=np.r_[Ax0[:K * nodes],
+                  Ax0[K * nodes:] + rng.uniform(0.1, 1.0, joint)],
+        lb=np.zeros(n), ub=np.full(n, np.inf), name=f"network_{seed}",
+    )
+    p.block_structure = {"num_blocks": K, "block_m": nodes, "link_m": joint}
+    return p
+
+
+def test_f64c_pair_assembly_matches_chunked(monkeypatch):
+    """The f64c Schur matrices built from the blocks' nonzero pairs must
+    give the chunked einsums' factors and solves to round-off, with a
+    ragged K grouping (5 + 5 + 2) and one dead block."""
+    import jax.numpy as jnp
+
+    from distributedlpsolver_tpu.backends import block_angular as B
+
+    inf = to_interior_form(_network_lp(11, 32, 24, 8, seed=3))
+    rep = {}
+    t, lay = B.build_tensors(inf, jnp.float64, pad_blocks=1, assembly=rep)
+    assert rep["assembly"] == "pairs" and lay.K == 12
+    monkeypatch.setattr(B, "_K_GROUP", 5)
+    reg = jnp.asarray(1e-10, jnp.float64)
+    rng = np.random.default_rng(5)
+    d = jnp.asarray(rng.uniform(0.5, 2.0, lay.n))
+    r = jnp.asarray(rng.standard_normal(lay.m))
+    ops_p = B._block_ops_f64c(t, lay, reg, chunk=7)
+    ops_d = B._block_ops_f64c(t._replace(pairs=None), lay, reg, chunk=7)
+    f_p, f_d = ops_p.factorize(d), ops_d.factorize(d)
+    for a, b in zip(f_p, f_d):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-12, atol=1e-12)
+    np.testing.assert_allclose(np.asarray(ops_p.solve(f_p, r)),
+                               np.asarray(ops_d.solve(f_d, r)),
+                               rtol=1e-12, atol=1e-12)
+
+
+def test_assembly_rule_follows_block_sparsity():
+    """Node-arc incidence blocks take the pairs, dense random tiles the
+    einsums; the pair count is every pair of nonzeros sharing a column,
+    counted here from the padded tensors."""
+    import jax.numpy as jnp
+
+    from distributedlpsolver_tpu.backends import block_angular as B
+
+    for p, want in (
+        (_network_lp(11, 32, 24, 8, seed=3), "pairs"),
+        (block_angular_lp(4, 12, 30, 8, seed=1, sparse=False), "dense"),
+    ):
+        rep = {}
+        t, lay = B.build_tensors(to_interior_form(p), jnp.float64,
+                                 assembly=rep)
+        assert rep["assembly"] == want
+        assert (t.pairs is None) == (want == "dense")
+        nB = np.count_nonzero(np.asarray(t.B_all), axis=1)
+        nL = np.count_nonzero(np.asarray(t.L_all), axis=1)
+        assert rep["pairs"] == int(np.sum(nB * nB + nL * nB + nL * nL))
+        dense = 2.0 * lay.K * lay.nb * (
+            lay.mb**2 + lay.link * lay.mb + lay.link**2)
+        assert rep["dense_flops_avoided"] == (dense if want == "pairs" else 0)
+        assert rep["padded_pairs"] * B._PAIR_ASSEMBLY_ADVANTAGE <= (
+            dense if want == "pairs" else 0)
+
+
+def _forced_f64c(monkeypatch):
+    """The huge-shape plan (f32 gram phase, then the f64c finisher) on a
+    small LP: the platform reads as TPU and the split budget is 0."""
+    from distributedlpsolver_tpu.backends import block_angular as B
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(B, "_F64_SPLIT_BUDGET", 0.0)
+    return B
+
+
+def test_f64c_finisher_same_iterations_under_both_assemblies(monkeypatch):
+    """Every iteration and the starting point in f64c (factor_dtype
+    float64 drops the f32 phase): the pair assembly and the chunked
+    einsums take the same path to 1e-8."""
+    B = _forced_f64c(monkeypatch)
+    p = _network_lp(6, 24, 24, 8, seed=7)
+    runs = {}
+    for advantage, want in ((B._PAIR_ASSEMBLY_ADVANTAGE, "pairs"),
+                            (10**30, "dense")):
+        monkeypatch.setattr(B, "_PAIR_ASSEMBLY_ADVANTAGE", advantage)
+        be = B.BlockAngularBackend()
+        r = solve(p, backend=be, segment_iters=4, factor_dtype="float64",
+                  verbose=False)
+        assert be.assembly["assembly"] == want
+        assert r.status == Status.OPTIMAL
+        assert r.rel_gap <= 1e-8 and r.pinf <= 1e-8 and r.dinf <= 1e-8
+        assert [(ph["mode"], ph["iters"]) for ph in r.phase_report] == [
+            ("f64c", r.iterations)]
+        runs[want] = r
+    assert runs["pairs"].iterations == runs["dense"].iterations
+    np.testing.assert_allclose(runs["pairs"].objective,
+                               runs["dense"].objective, rtol=1e-9)
+    ref = highs_on_general(p)
+    np.testing.assert_allclose(runs["pairs"].objective, ref.fun, rtol=1e-6,
+                               atol=1e-7)
+
+
+def test_f64c_phase_report_and_build_span_carry_the_assembly(
+        monkeypatch, tmp_path):
+    import json
+
+    from distributedlpsolver_tpu.obs import trace as obs_trace
+
+    B = _forced_f64c(monkeypatch)
+    be = B.BlockAngularBackend()
+    tracer = obs_trace.Tracer(str(tmp_path / "trace.json"))
+    prev = obs_trace.set_tracer(tracer)
+    try:
+        r = solve(_network_lp(6, 24, 24, 8, seed=7), backend=be,
+                  segment_iters=4, verbose=False)
+    finally:
+        obs_trace.set_tracer(prev)
+    assert r.status == Status.OPTIMAL
+    rows = {ph["mode"]: ph for ph in r.phase_report}
+    assert rows["f64c"]["assembly"] == "pairs"
+    assert rows["f64c"]["pairs"] == be.assembly["pairs"] > 0
+    assert rows["f64c"]["dense_flops_avoided"] > 0
+    assert "assembly" not in rows["mixed"]
+    # The f64c row's rate is folded with the pair assembly's flops.
+    assert rows["f64c"]["flops_per_iter"] == be._f64_flops
+    assert rows["mixed"]["flops_per_iter"] == be._dense_flops > be._f64_flops
+    events = json.loads(open(tracer.close()).read())["traceEvents"]
+    build = [e for e in events if e["name"] == "lp.build"]
+    assert build and build[0]["args"] == be.assembly
+
+
+@pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 virtual devices")
+def test_f64c_pair_assembly_on_a_mesh_matches_unsharded():
+    """Pair lists shard over the block axis like B_all (6 blocks over 8
+    devices: two dead ones) and the linking factor over the mesh; the
+    f64c solve matches the unsharded chunked one."""
+    import jax.numpy as jnp
+
+    from distributedlpsolver_tpu.backends import block_angular as B
+
+    inf = to_interior_form(_network_lp(6, 24, 24, 8, seed=7))
+    be = B.BlockAngularBackend(mesh=make_mesh(axis_names=("blocks",)))
+    be.setup(inf, SolverConfig())
+    assert be._lay.K == 8 and be.assembly["assembly"] == "pairs"
+    t, lay = B.build_tensors(inf, jnp.float64)
+    reg = jnp.asarray(1e-10, jnp.float64)
+    rng = np.random.default_rng(2)
+    d = jnp.asarray(rng.uniform(0.5, 2.0, lay.n))
+    r = jnp.asarray(rng.standard_normal(lay.m))
+
+    @jax.jit
+    def sharded(t, d, r):
+        ops = B._block_ops_f64c(t, be._lay, reg, link_shard=be._link_shard)
+        return ops.solve(ops.factorize(d), r)
+
+    ops = B._block_ops_f64c(t._replace(pairs=None), lay, reg, chunk=7)
+    np.testing.assert_allclose(
+        np.asarray(sharded(be._tensors, d, r)),
+        np.asarray(ops.solve(ops.factorize(d), r)), rtol=1e-10, atol=1e-10)
+
+
+def test_pair_lists_of_one_layout_share_a_padded_shape():
+    """Two networks of one layout with other random arcs get pair lists
+    of one padded shape, so a second LP reuses the first's programs: T
+    and r are rounded up to a quarter of their power of two."""
+    import jax.numpy as jnp
+
+    from distributedlpsolver_tpu.backends import block_angular as B
+
+    assert [B._bucket(x) for x in (0, 1, 7, 8, 9, 11, 9959, 9969)] == [
+        1, 1, 7, 8, 10, 12, 10240, 10240]
+    shapes = []
+    for seed in (3, 4):
+        inf = to_interior_form(_network_lp(11, 32, 24, 8, seed=seed))
+        t, lay = B.build_tensors(inf, jnp.float64)
+        shapes.append((tuple(lay), [a.shape for a in t.pairs]))
+    assert shapes[0] == shapes[1]

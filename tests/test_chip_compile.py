@@ -69,3 +69,31 @@ def test_chol_mxu_factor_compiles_for_v5e_f64(one_chip):
     compiled = chol_mxu_factor.lower(M).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < _V5E_HBM
+
+
+def test_f64c_pair_assembly_compiles_for_v5e(one_chip):
+    """The f64c Schur matrices from nonzero-pair lists (gathers, then
+    unique-index scatters) at the pds-10 layout: K=11, mb=1,399,
+    nb=4,433, link=1,169, with the lists' padded shapes there."""
+    from distributedlpsolver_tpu.backends.block_angular import _pair_sum
+
+    K, mb, nb, link = 11, 1399, 4433, 1169
+    f64, i32 = jnp.float64, jnp.int32
+
+    def assemble(dBp, mc, mf, mt, gc, gf, gt, lc, lf, lt):
+        return (
+            _pair_sum(mc, mf, mt, dBp, mb * mb).reshape(K, mb, mb),
+            _pair_sum(gc, gf, gt, dBp, link * mb).reshape(K, link, mb),
+            _pair_sum(lc, lf, lt, dBp.reshape(1, -1), link * link),
+        )
+
+    args = [_spec((K, nb + 1), f64, one_chip)]
+    for k, r, T in ((K, 10, 10240), (K, 1, 2560), (1, 12, 1280)):
+        args += [_spec((k, r, T), i32, one_chip), _spec((k, r, T), f64, one_chip),
+                 _spec((k, T), i32, one_chip)]
+    compiled = jax.jit(assemble).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert "scatter" in compiled.as_text()
+    # The outputs and one padded copy each: far under the dense
+    # assembly's chunk temps (2 GB budget).
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 2e9

@@ -140,3 +140,25 @@ def test_a_reused_backend_reports_no_phases_of_an_earlier_solve():
     # The CPU's default segment setting runs one fused program: no phases.
     again = solve(p, backend=be, verbose=False)
     assert again.status == Status.OPTIMAL and again.phase_report is None
+
+
+def test_annotate_adds_args_to_the_innermost_open_span_of_that_name(
+        tmp_path):
+    tracer = obs_trace.Tracer(str(tmp_path / "trace.json"))
+    prev = obs_trace.set_tracer(tracer)
+    try:
+        with obs_trace.span("lp.build"):
+            with obs_trace.span("lp.build", depth=2):
+                with obs_trace.span("lp.detect"):
+                    obs_trace.annotate("lp.build", assembly="pairs")
+            obs_trace.annotate("lp.start", ignored=True)  # none open
+    finally:
+        obs_trace.set_tracer(prev)
+    obs_trace.annotate("lp.build", after=True)  # nothing open: no-op
+    events = json.loads(open(tracer.close()).read())["traceEvents"]
+    xs = [e for e in events if e["ph"] == "X"]
+    assert [(e["name"], e.get("args")) for e in xs] == [
+        ("lp.detect", None),
+        ("lp.build", {"depth": 2, "assembly": "pairs"}),
+        ("lp.build", None),
+    ]
