@@ -3,14 +3,16 @@
     python3 -m benchmark.control --workload <cell> --seeds 11,12,13 --mode control|program
 
 For each seed, the LPs the cell's window would get from that seed (k = 0,
-1, ...) go through ``ipm.solve`` at the cell's sizes, and the
-configuration's reference judges every answer. ``program`` runs the solve options the
-configuration states: its readings are the lower ones. ``control`` runs
-them with the configuration's ``control`` options on top, the program's
-own path in the precision below the stated one (float32 for float64):
-its readings are the upper ones, which the limits must fail. One JSON
-line per seed gives the worst of each compared number. The benchmark's
-runs never run this; the tests run it at a small size.
+1, ...) go through the cell's own entry (``entries/<entry>.py``: the
+calls of the program that the window times) at the cell's sizes, and the
+configuration's reference judges every answer. ``program`` runs the
+solve options the configuration states: its readings are the lower ones.
+``control`` runs them with the configuration's ``control`` options on
+top, the program's own path in the precision below the stated one
+(float32 for float64): its readings are the upper ones, which the limits
+must fail. One JSON line per seed gives the worst of each compared
+number. The benchmark's runs never run this; the tests run it at a small
+size.
 """
 
 from __future__ import annotations
@@ -21,29 +23,18 @@ import sys
 import time
 
 
-def _solve_answers(cell, seed, n, opts):
-    from distributedlpsolver_tpu.ipm import solve
-
-    out = []
-    for k in range(n):
-        r = solve(cell.generator.build(cell.config["params"], seed, k), **opts)
-        out.append({"k": k, "status": r.status.value, "x": r.x, "y": r.y,
-                    "objective": float(r.objective),
-                    "iterations": int(r.iterations)})
-    return out
-
-
-def readings(cell, seeds, mode: str, answers: int, emit=print):
-    """Yield one row per seed: statuses and the worst compared numbers."""
+def readings(cell, seeds, mode: str, answers=None, emit=print):
+    """One row per seed: statuses and the worst compared numbers over the
+    LPs k < ``answers`` (default: the window's pool)."""
     from benchmark.run import check
 
-    opts = dict(cell.config.get("solve", {}))
-    if mode == "control":
-        opts.update(cell.config["control"]["solve"])
     rows = []
     for seed in seeds:
+        entry = cell.entry.Entry(cell, seed)
+        if mode == "control":
+            entry.opts.update(cell.config["control"]["solve"])
         t0 = time.perf_counter()
-        ans = _solve_answers(cell, seed, answers, opts)
+        ans = entry.answers(answers)
         nums = check(cell, seed, ans, any_status=True)
         row = {"seed": seed, "mode": mode, "answers": len(ans),
                "statuses": sorted({a["status"] for a in ans}),
@@ -63,7 +54,7 @@ def main(argv=None) -> int:
     ap.add_argument("--mode", choices=("program", "control"),
                     required=True)
     ap.add_argument("--answers", type=int, default=None,
-                    help="LPs per seed (default: the mix's pool)")
+                    help="LPs per seed (default: the LPs of the mix's pool)")
     args = ap.parse_args(argv)
     from benchmark import run, spec
 
@@ -74,10 +65,8 @@ def main(argv=None) -> int:
         print(f"control: {e}; nothing was run", file=sys.stderr)
         return 3
     run.use_cache(spec.ROOT)
-    t = cell.traffic
-    n = args.answers or int(t.get("pool", 1))
     seeds = [int(s) for s in args.seeds.split(",")]
-    readings(cell, seeds, args.mode, n)
+    readings(cell, seeds, args.mode, args.answers)
     return 0
 
 

@@ -1,20 +1,26 @@
-"""The one traffic driver. A mix is a data file (``traffic/<mix>.json``)
-of parameters for it:
+"""What the traffic entries share. A mix is a data file
+(``traffic/<mix>.json``) of parameters; its ``entry`` names the driver,
+``entries/<entry>.py``, which reads the rest (see each entry's
+docstring). An entry module defines ``Entry(cell, seed)`` with:
 
-- ``entry``: ``"solve"``, back-to-back ``ipm.solve`` calls on the calling
-  thread, one LP at a time (a served entry is for the PR that adds a
-  served cell);
-- ``pool``: LPs built in set-up, as many as the window solves; no
-  instance repeats in a window while the pool lasts;
-- ``warm``: LPs solved in set-up to compile every program the window
-  runs (same sizes and solve options as the window's, other instances);
-- ``trace_seconds``: the bounded part of the window that a ``--trace 1``
-  run profiles, its first seconds (the profiler is stopped by a timer, in
-  the middle of a solve if need be).
+- ``setup()``: builds the window's LPs and solves warm-up LPs at the
+  window's exact solve options, so every program the window runs
+  compiles or loads from the cache here; returns notes;
+- ``window(seconds, profiler)``: drives the system from window start for
+  ``seconds`` (what is in flight at the close runs to its end); returns
+  (answers, window start, notes), one answer per LP with its ``k``,
+  ``status``, ``x``, ``y``, ``t_submit`` and ``t_done``;
+- ``answers(n=None)``: the answers to LPs k = 0 … n−1 (default: the
+  window's pool) through the entry's own calls at ``opts``, the solve
+  options, on which the control lays its own (``control.py``);
+- ``close()``: frees what set-up built.
 
 Every LP is ``generator.build(params, seed, k)``: the window's LPs have
 k = 0, 1, 2, ... and the set-up's warm-up LPs k ≥ ``WARM_BASE``, so a
 seed fixes every input and no warm-up LP is one of the window's.
+``trace_seconds`` in a mix bounds the part of the window that a
+``--trace 1`` run profiles, its first seconds (the profiler is stopped
+by a timer, in the middle of a solve if need be).
 """
 
 from __future__ import annotations
@@ -75,69 +81,3 @@ class Profiler:
         if self._timer is not None:
             self._timer.cancel()
             self._timer.join()
-
-
-def _solve_opts(config: dict) -> dict:
-    return dict(config.get("solve", {}))
-
-
-class SolveEntry:
-    """``ipm.solve`` on this thread; the window's LPs are built in set-up."""
-
-    def __init__(self, cell, seed):
-        self.cell, self.seed = cell, seed
-        self.params = cell.config["params"]
-        self.opts = _solve_opts(cell.config)
-
-    def build(self, k):
-        return self.cell.generator.build(self.params, self.seed, k)
-
-    def setup(self) -> dict:
-        t = self.cell.traffic
-        routes = []
-        for i in range(int(t.get("warm", 1))):
-            routes.append(self._solve(self.build(WARM_BASE + i), -1)["backend"])
-        self.pool = [self.build(k) for k in range(int(t.get("pool", 1)))]
-        return {"warm_routes": routes, "pool": len(self.pool)}
-
-    def _solve(self, problem, k):
-        from distributedlpsolver_tpu.ipm import solve
-
-        t0 = time.perf_counter()
-        with _span("bench.solve"):
-            r = solve(problem, **self.opts)
-        t1 = time.perf_counter()
-        return {
-            "k": k, "t_submit": t0, "t_done": t1, "status": r.status.value,
-            "iterations": int(r.iterations), "objective": float(r.objective),
-            "x": r.x, "y": r.y, "backend": r.backend,
-            "solve_time": float(r.solve_time),
-            "setup_time": float(r.setup_time),
-        }
-
-    def window(self, seconds, profiler) -> tuple[list, float, dict]:
-        """Back-to-back solves from window start; the one in flight at
-        the close runs to its end. Returns (answers, window start,
-        notes)."""
-        answers = []
-        t0 = time.perf_counter()
-        profiler.start(self.cell.traffic.get("trace_seconds"))
-        k = 0
-        while time.perf_counter() < t0 + seconds:
-            i = k % len(self.pool)
-            answers.append(self._solve(self.pool[i], i))
-            k += 1
-        profiler.stop()
-        profiler.join()
-        solves = [{n: a[n] for n in ("k", "status", "iterations",
-                                     "solve_time", "setup_time")}
-                  | {"wall": a["t_done"] - a["t_submit"]} for a in answers]
-        return answers, t0, {"repeats": max(0, k - len(self.pool)),
-                             "routes": sorted({a["backend"] for a in answers}),
-                             "solves": solves}
-
-    def close(self):
-        self.pool = []
-
-
-ENTRIES = {"solve": SolveEntry}

@@ -22,7 +22,9 @@ closes the gap with weight on an infinite bound. Compared numbers:
 
 - ``primal_rel``: the largest violation of rlb ≤ Ax ≤ rub and
   lb ≤ x ≤ ub, over 1 + the largest finite bound;
-- ``gap_rel``: as above.
+- ``gap_rel``: as above; NaN, which fails any limit, where the answer
+  carries no y or a y of another length than the rows: a path that
+  returns no dual has given no certificate.
 
 The objective the program reports is not compared: it is cᵀx of its own
 x, so no run, the float32 control's included, ever reads apart from it.
@@ -58,6 +60,8 @@ def primal_rel(p, x) -> float:
 
 def check(p, answer: dict) -> dict:
     x = np.asarray(answer["x"], dtype=np.float64)
+    if answer.get("y") is None or np.shape(answer["y"]) != (p.m,):
+        return {"primal_rel": primal_rel(p, x), "gap_rel": float("nan")}
     y = np.asarray(answer["y"], dtype=np.float64)
     ax = np.asarray(p.A @ x, dtype=np.float64).ravel()
     r = p.c - np.asarray(p.A.T @ y, dtype=np.float64).ravel()

@@ -4,13 +4,14 @@
 
 Stages: set-up (build the cell's LPs from the seed and solve the warm-up
 LPs at the window's exact solve options, so every program compiles or
-loads from the cache here), the window (``load.py``'s driver for
-``--seconds``), the check (every answer of the window against the
-configuration's float64 reference), the result. Earlier stdout lines are
-JSON objects (``setup``, ``window``, ``trace``); the last stdout line is
-the result, and the last stderr lines each compared number beside its
-limit. No TPU, fewer chips than the cell asks for, or a chip kind with no
-published peaks: exit 3 with no result.
+loads from the cache here), the window (the driver that the mix names,
+``entries/<entry>.py``, for ``--seconds``), the check (every answer of
+the window against the configuration's float64 reference), the result.
+Earlier stdout lines are JSON objects (``setup``, ``window``,
+``trace``); the last stdout line is the result, and the last stderr
+lines each compared number beside its limit. No TPU, fewer chips than
+the cell asks for, or a chip kind with no published peaks: exit 3 with
+no result.
 """
 
 from __future__ import annotations
@@ -108,7 +109,7 @@ def run(args, root: str = spec.ROOT, require_tpu: bool = True) -> int:
         return 3
     cache_dir = use_cache(root)
     compiles = meter.CompileMeter()
-    entry = load.ENTRIES[cell.traffic["entry"]](cell, args.seed)
+    entry = cell.entry.Entry(cell, args.seed)
     c0 = compiles.snapshot()
     info = entry.setup()
     emit({"setup": {**info, "cache_dir": cache_dir,

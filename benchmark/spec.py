@@ -7,11 +7,15 @@ files that hold them sit at fixed places under ``benchmark/``:
   sizes, tolerance, guarantees, the limits of the correctness check);
 - ``generators/<generator>.py``: ``build(params, seed, k) -> LPProblem``;
 - ``references/<reference>.py``: ``check(problem, answer) -> {name: x}``;
-- ``traffic/<traffic>.json``: the parameters of ``load.py``'s driver;
+- ``traffic/<traffic>.json``: the mix: its ``entry`` and that entry's
+  parameters;
+- ``entries/<entry>.py``: the driver a mix names, ``Entry(cell, seed)``
+  with ``setup()``, ``window(seconds, profiler)``, ``answers(n)`` and
+  ``close()`` (see ``load.py``);
 - ``metrics/<metric>.py``: ``read(run) -> float | None``.
 
-A later cell, mix, generator, reference or metric is a new file and a new
-entry: nothing here needs an edit.
+A later cell, mix, entry, generator, reference or metric is a new file
+and a new entry: nothing here needs an edit.
 """
 
 from __future__ import annotations
@@ -43,6 +47,7 @@ class Cell:
     traffic: dict
     generator: object  # module with build()
     reference: object  # module with check()
+    entry: object  # module with Entry
     metrics: dict  # kind -> [Metric]
 
 
@@ -81,6 +86,8 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
         os.path.join(here, "generators", config["generator"] + ".py"))
     reference = load_module(
         os.path.join(here, "references", config["reference"] + ".py"))
+    entry = load_module(
+        os.path.join(here, "entries", traffic["entry"] + ".py"))
     e2e = [m for m in bench["end_to_end"] if _applies(m, workload, set())]
     e2e_names = {m["name"] for m in e2e}
     layer = [m for m in bench["per_layer"]
@@ -94,4 +101,4 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
             for m in entries
         ]
     return Cell(workload, int(w["chips"]), config, traffic, generator,
-                reference, metrics)
+                reference, entry, metrics)

@@ -150,6 +150,18 @@ def test_kkt_reference_accepts_an_optimum_and_rejects_faults():
     assert kkt.check(p, dict(ans, y=-ans["y"]))["gap_rel"] > 1e-3
 
 
+@pytest.mark.parametrize("y", [None, "short"])
+def test_kkt_reference_reads_a_missing_dual_as_no_certificate(y):
+    """No y, or a y of another length than the rows: the gap is NaN, which
+    fails any limit (a path that returns no dual is incorrect, and the
+    check does not crash); the primal residual is still read."""
+    p = _tiny_lp()
+    ans = _highs_answer(p)
+    nums = kkt.check(p, dict(ans, y=None if y is None else ans["y"][:-1]))
+    assert np.isnan(nums["gap_rel"])
+    assert nums["primal_rel"] == kkt.check(p, ans)["primal_rel"]
+
+
 def test_kkt_reference_prices_weight_on_an_infinite_bound():
     """min x1 + x2 s.t. x1 + x2 >= 1, x >= 0 (optimum 1). The feasible,
     suboptimal x = (1, 1) with y = 2 closes the gap if the reduced costs
