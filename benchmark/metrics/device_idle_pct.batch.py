@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device,
+from the profiler trace (``trace.py``); none without a trace."""
+
+
+def read(run):
+    t = run["trace"]
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"]) if t else None

@@ -39,6 +39,7 @@ from distributedlpsolver_tpu.ipm.config import SolverConfig
 from distributedlpsolver_tpu.ipm.state import IPMState, Status
 from distributedlpsolver_tpu.models.generators import BatchedLP
 from distributedlpsolver_tpu.obs import metrics as obs_metrics
+from distributedlpsolver_tpu.obs import trace as obs_trace
 from distributedlpsolver_tpu.parallel import mesh as mesh_lib
 
 _RUNNING, _OPTIMAL, _MAXITER, _NUMERR = 0, 1, 2, 3
@@ -66,8 +67,9 @@ class BatchedResult:
     # Iterations fused per while-loop trip of the device loop (the
     # serve telemetry's fused-iterations-per-dispatch figure).
     fused_iters: int = 1
-    # Full final iterates of the bucket path (the warm cache stores the
-    # complete (x, y, s, w, z) per member); None where not populated.
+    # Duals (B, m): every route fills y. The bucket path also fills the
+    # rest of the final iterate (the warm cache stores the complete
+    # (x, y, s, w, z) per member); None where not populated.
     y: Optional[np.ndarray] = None
     s: Optional[np.ndarray] = None
     w: Optional[np.ndarray] = None
@@ -76,6 +78,9 @@ class BatchedResult:
     # iterate was offered AND survived the in-program safeguard; False
     # for cold slots and safeguard fallbacks. None off the bucket path.
     warm_used: Optional[np.ndarray] = None
+    # solve_batched's counts over the whole call (see _COUNTERS); None
+    # on the bucket path.
+    counters: Optional[dict] = None
 
     @property
     def n_optimal(self) -> int:
@@ -306,12 +311,14 @@ def _solve_batched_jit(
     )
 
     carry = _fresh_batch_carry(states0, jnp.zeros(B, jnp.int32), B, reg0, dtype)
+    phase_its = []  # each phase's loop iterations, for the lane count
     if two_phase:
         carry = _batched_phase(
             A, data, carry, params_p1, max_iter, max_refactor, reg_grow,
             jnp.dtype(jnp.float32), None, stall_window, _RUNNING, A32,
             fuse_iters=fuse_iters,
         )
+        phase_its.append(carry[2])
         # keep states + per-problem iters; reset provisional verdicts
         carry = _fresh_batch_carry(carry[0], carry[6], B, reg0, dtype)
     if cg_iters:
@@ -324,14 +331,16 @@ def _solve_batched_jit(
             jnp.dtype(jnp.float32), None, stall_window, _RUNNING, A32,
             cg_iters, cg_tol, fuse_iters,
         )
+        phase_its.append(carry[2])
         carry = _fresh_batch_carry(
             carry[0], carry[6], B, reg0, dtype, status=carry[5]
         )
-    states, active, _, _, _, status, iters, _, _ = _batched_phase(
+    states, active, it, _, _, status, iters, _, _ = _batched_phase(
         A, data, carry, params, max_iter, max_refactor, reg_grow, fdt,
         None, 2 * stall_window if stall_window else 0, _STALL,
         A32 if fdt == f32 else None, fuse_iters=fuse_iters,
     )
+    phase_its.append(it)
     status = jnp.where(status == _RUNNING, _MAXITER, status)
 
     # Final per-problem diagnostics.
@@ -341,7 +350,8 @@ def _solve_batched_jit(
         return pinf, dinf, rel_gap, pobj
 
     pinf, dinf, rel_gap, pobj = jax.vmap(final_norms)(A, data, states)
-    return states, status, iters, pinf, dinf, rel_gap, pobj
+    return (states, status, iters, pinf, dinf, rel_gap, pobj,
+            jnp.stack(phase_its))
 
 
 _CHUNK_DEFAULT = 256  # per-device-program batch slice; see solve_batched
@@ -454,6 +464,16 @@ def _cast_batch_carry(carry, dtype):
 
 _COMPACT_FLOOR = 32  # smallest compacted program size
 
+# solve_batched's counters (BatchedResult.counters, and the call's record
+# in obs.trace.recent_solves()), each summed over the call's chunks:
+# programs run; gathers to a smaller program; Σ members' iterations in the
+# batched loop, before the solo clean-up; Σ over segments of iterations
+# run × the program's size (every member it holds, finished ones masked);
+# members the solo clean-up re-solved. Read on the host from values the
+# drive fetches anyway: counting adds no device synchronisation.
+_COUNTERS = ("chunks", "compactions", "member_iters", "lane_iters",
+             "cleanup_lps")
+
 
 def _compact_gather(carry, order, keep_idx, new_size, B):
     """Gather the ``keep_idx`` members of a batched carry into a
@@ -493,8 +513,8 @@ def _scatter_out(outs, order, carry):
 
 
 def _solve_batched_segmented(
-    A, data, cfg, params, params_p1, fname, two_phase, seg, cg=(0, 0.0),
-    compact_ok=False, fuse_iters=1,
+    A, data, cfg, params, params_p1, fname, two_phase, seg, counters,
+    cg=(0, 0.0), compact_ok=False, fuse_iters=1,
 ):
     """Host-segmented batched solve: same phases as _solve_batched_jit but
     each device program is bounded to ~15s (the segmenting rule of
@@ -510,7 +530,10 @@ def _solve_batched_segmented(
     member needs 16, so ~60% of step compute was spent advancing frozen
     members. Program sizes are fixed halvings, so each size compiles
     once and is reused by every chunk. Disabled under a mesh (the batch
-    axis is sharded; gathers would reshard it)."""
+    axis is sharded; gathers would reshard it).
+
+    ``counters`` (a dict over _COUNTERS) gains the drive's lane
+    iterations and compactions."""
     B = A.shape[0]
     dtype = A.dtype
     f32 = jnp.float32
@@ -611,10 +634,10 @@ def _solve_batched_segmented(
         if final and compact_ok and B >= 2 * _COMPACT_FLOOR:
             carry = _drive_compacting(
                 mk_run_seg, carry, Ap, datap, A32p, cfg, seg, B, tail,
-                cleanup_cap, dtype,
+                cleanup_cap, dtype, counters,
             )
         else:
-            carry, _ = core.drive_segments(
+            carry, (it_ph, *_) = core.drive_segments(
                 mk_run_seg(Ap, datap, A32p), carry, cfg.max_iter, 0, seg,
                 early_stop=(
                     (
@@ -627,6 +650,7 @@ def _solve_batched_segmented(
                     else None
                 ),
             )
+            counters["lane_iters"] += B * it_ph
         phase_report.append({
             "phase": pi,
             "mode": ("f32-state" if f32_state
@@ -651,12 +675,17 @@ def _solve_batched_segmented(
 
 
 def _drive_compacting(
-    mk_run_seg, carry, A, data, A32, cfg, seg, B, tail, cleanup_cap, dtype
+    mk_run_seg, carry, A, data, A32, cfg, seg, B, tail, cleanup_cap, dtype,
+    counters,
 ):
     """Final-phase segment drive with program compaction (see
     _solve_batched_segmented). Returns a FULL-SIZE carry whose states /
     status / iters lanes hold every member's final values (the only
-    lanes the caller consumes after the final phase)."""
+    lanes the caller consumes after the final phase). Adds its lane
+    iterations and compactions to ``counters``; each compaction runs in
+    a ``batch.compact`` span (the host read of the active mask and the
+    gathers' dispatch — the gathers themselves finish in the next
+    segment's read)."""
     states_out = jax.tree_util.tree_map(
         lambda v: jnp.zeros((B + 1,) + v.shape[1:], v.dtype), carry[0]
     )
@@ -686,6 +715,7 @@ def _drive_compacting(
             run_seg, carry, cfg.max_iter, 0, min(seg, 8), target_s=4.0,
             early_stop=early, it0_status0=(it_g, status_g), seg_cap=8,
         )
+        counters["lane_iters"] += size * (it_g - prev_it)
         n_act, n_unf = int(n_act), int(n_unf)
         if (
             status_g != core.STATUS_RUNNING
@@ -701,24 +731,28 @@ def _drive_compacting(
         ):
             break
         # Shrink: gather actives into the smallest half-size that fits.
-        act = np.asarray(carry[1])
-        stat_host = np.asarray(carry[5])
-        keep = np.flatnonzero(act)
-        new_size = size // 2
-        while new_size > _COMPACT_FLOOR and len(keep) <= new_size // 2:
-            new_size //= 2
-        if len(keep) > new_size:
-            break  # defensive: actives cannot exceed the early trigger
-        out_nonopt += int(np.sum(~act & (stat_host != _OPTIMAL)))
-        states_out, status_out, iters_out = _scatter_out(
-            (states_out, status_out, iters_out), order, carry
-        )
-        carry, order, sel = _compact_gather(carry, order, keep, new_size, B)
-        A = A[sel]
-        A32 = A32[sel] if A32 is not None else None
-        data = jax.tree_util.tree_map(lambda v: v[sel], data)
-        size = new_size
-        run_seg = mk_run_seg(A, data, A32)
+        with obs_trace.span("batch.compact"):
+            act = np.asarray(carry[1])
+            stat_host = np.asarray(carry[5])
+            keep = np.flatnonzero(act)
+            new_size = size // 2
+            while new_size > _COMPACT_FLOOR and len(keep) <= new_size // 2:
+                new_size //= 2
+            if len(keep) > new_size:
+                break  # defensive: actives cannot exceed the early trigger
+            out_nonopt += int(np.sum(~act & (stat_host != _OPTIMAL)))
+            states_out, status_out, iters_out = _scatter_out(
+                (states_out, status_out, iters_out), order, carry
+            )
+            carry, order, sel = _compact_gather(
+                carry, order, keep, new_size, B
+            )
+            A = A[sel]
+            A32 = A32[sel] if A32 is not None else None
+            data = jax.tree_util.tree_map(lambda v: v[sel], data)
+            size = new_size
+            run_seg = mk_run_seg(A, data, A32)
+            counters["compactions"] += 1
     states_out, status_out, iters_out = _scatter_out(
         (states_out, status_out, iters_out), order, carry
     )
@@ -1496,13 +1530,10 @@ def _concat_results(parts, solve_time, setup_time) -> BatchedResult:
         dinf=cat("dinf"),
         solve_time=solve_time,
         setup_time=setup_time,
-        # Flat rows with a chunk tag — same shape chunked or not, so
-        # consumers never branch on the solve's chunking.
-        phase_report=[
-            {**ph, "chunk": ci}
-            for ci, p in enumerate(parts)
-            for ph in (p.phase_report or [])
-        ],
+        # Flat rows with a chunk tag (_solve_chunk) — same shape chunked
+        # or not, so consumers never branch on the solve's chunking.
+        phase_report=[ph for p in parts for ph in p.phase_report],
+        y=cat("y"),
     )
 
 
@@ -1519,13 +1550,24 @@ def solve_batched(
     ``mesh`` shards the batch axis (data parallelism); the batch size must
     then be divisible by the mesh size.
 
-    ``chunk`` bounds how many problems one device program holds (HBM: the
-    per-iteration temps of B emulated-f64 batched GEMMs are ~64·B·m·n
-    bytes — B=1024×(128,512) alone exceeds a v5e's 16 GB). Chunks run
-    sequentially through ONE compiled executable, so throughput is
+    ``chunk`` bounds how many problems one device program holds. Chunks
+    run sequentially through ONE compiled executable, so throughput is
     unaffected once B saturates the chip. Default: 256 on TPU (None —
     no chunking — elsewhere); chunking preserves mesh divisibility by
-    requiring chunk % mesh size == 0.
+    requiring chunk % mesh size == 0. Device memory, measured on one
+    TPU v5e: 1,024 members of 128×512 in 256-member chunks peak at
+    370,329,600 bytes (``memory_peak_bytes`` of the benchmark cell
+    ``lp128x512.batch``), one chunk's A (134 MB) included.
+
+    Every member comes back with its primal x and its duals y of Ax = b.
+    The call keeps its own :func:`obs.trace.collect_spans` table, per
+    chunk: ``batch.prepare`` (host cast, transfer, problem data),
+    ``batch.loop`` (args ``chunk``, ``size``: the device loop, closed
+    once its results are on the device; ``batch.compact`` nested in it),
+    ``batch.readback`` (device → host) and ``batch.cleanup`` (the solo
+    re-solves; opened even when none is needed). It leaves one record —
+    those spans, ``phase_report`` and ``BatchedResult.counters`` (see
+    ``_COUNTERS``) — in :func:`obs.trace.recent_solves`.
     """
 
     cfg = config or SolverConfig()
@@ -1537,9 +1579,6 @@ def solve_batched(
     # left optimality (1023/1024, 133.8 s vs 115.7 s) — the second
     # round is what keeps the hard tail's directions accurate enough
     # to converge (A/B 2026-08-01).
-    dtype = jnp.dtype(cfg.dtype)
-    fname = jnp.dtype(cfg.factor_dtype_resolved()).name
-
     B_total = np.asarray(batch.A).shape[0]
     if chunk is None and jax.default_backend() == "tpu":
         chunk = _CHUNK_DEFAULT
@@ -1547,59 +1586,76 @@ def solve_batched(
         raise ValueError(
             f"chunk {chunk} not divisible by mesh axis {mesh.shape[batch_axis]}"
         )
-    if chunk and B_total > chunk:
-        # A non-multiple B leaves one smaller remainder chunk (one extra
-        # compile at that shape) — still chunked: falling through to a
-        # single whole-batch program is exactly the HBM blow-up chunking
-        # exists to prevent. With a mesh, the remainder must still divide
-        # the mesh axis (checked by the recursive call).
+    counters = dict.fromkeys(_COUNTERS, 0)
+    with obs_trace.collect_spans() as spans:
         t0 = time.perf_counter()
-        parts = [
-            solve_batched(
-                BatchedLP(
-                    c=batch.c[i : i + chunk],
-                    A=batch.A[i : i + chunk],
-                    b=batch.b[i : i + chunk],
-                    name=f"{batch.name}[{i}:{i + chunk}]",
-                ),
-                cfg,
-                mesh=mesh,
-                batch_axis=batch_axis,
-                chunk=0,  # no further splitting
+        if chunk and B_total > chunk:
+            # A non-multiple B leaves one smaller remainder chunk (one
+            # extra compile at that shape) — still chunked: falling
+            # through to a single whole-batch program is exactly the HBM
+            # blow-up chunking exists to prevent. With a mesh, the
+            # remainder must still divide the mesh axis (checked per
+            # chunk).
+            parts = [
+                _solve_chunk(
+                    BatchedLP(
+                        c=batch.c[i : i + chunk],
+                        A=batch.A[i : i + chunk],
+                        b=batch.b[i : i + chunk],
+                        name=f"{batch.name}[{i}:{i + chunk}]",
+                    ),
+                    cfg, mesh, batch_axis, ci, counters,
+                )
+                for ci, i in enumerate(range(0, B_total, chunk))
+            ]
+            wall = time.perf_counter() - t0
+            solve_time = sum(p.solve_time for p in parts)
+            result = _concat_results(
+                parts,
+                solve_time=solve_time,
+                # wall minus solve, no double count
+                setup_time=max(wall - solve_time, 0.0),
             )
-            for i in range(0, B_total, chunk)
-        ]
-        wall = time.perf_counter() - t0
-        solve_time = sum(p.solve_time for p in parts)
-        return _concat_results(
-            parts,
-            solve_time=solve_time,
-            setup_time=max(wall - solve_time, 0.0),  # wall minus solve, no double count
-        )
+        else:
+            result = _solve_chunk(batch, cfg, mesh, batch_axis, 0, counters)
+        t1 = time.perf_counter()
+    result.counters = counters
+    obs_trace.record_solve(t0, t1, spans, result.phase_report, counters)
+    return result
 
-    t0 = time.perf_counter()
-    A = np.asarray(batch.A, dtype=dtype)
-    b = np.asarray(batch.b, dtype=dtype)
-    c = np.asarray(batch.c, dtype=dtype)
-    Bsz, m, n = A.shape
-    if mesh is not None:
-        if Bsz % mesh.shape[batch_axis] != 0:
-            raise ValueError(
-                f"batch {Bsz} not divisible by mesh axis {mesh.shape[batch_axis]}"
-            )
-        sh = lambda nd: mesh_lib.batch_sharding(mesh, nd, batch_axis)
-        A = jax.device_put(A, sh(3))
-        b = jax.device_put(b, sh(2))
-        c = jax.device_put(c, sh(2))
-    else:
-        A, b, c = jnp.asarray(A), jnp.asarray(b), jnp.asarray(c)
 
-    u = jnp.full((Bsz, n), jnp.inf, dtype=dtype)
-    data = jax.vmap(lambda cc, bb, uu: core.make_problem_data(jnp, cc, bb, uu, dtype))(
-        c, b, u
-    )
-    params = cfg.step_params()
-    setup_time = time.perf_counter() - t0
+def _solve_chunk(batch, cfg, mesh, batch_axis, ci, counters) -> BatchedResult:
+    """One device program's members (chunk ``ci``, or the whole batch):
+    the batched loop, the read back to the host and the solo clean-up,
+    each in its span; adds its counts to ``counters``."""
+    dtype = jnp.dtype(cfg.dtype)
+    fname = jnp.dtype(cfg.factor_dtype_resolved()).name
+
+    with obs_trace.span("batch.prepare"):
+        t0 = time.perf_counter()
+        A = np.asarray(batch.A, dtype=dtype)
+        b = np.asarray(batch.b, dtype=dtype)
+        c = np.asarray(batch.c, dtype=dtype)
+        Bsz, m, n = A.shape
+        if mesh is not None:
+            if Bsz % mesh.shape[batch_axis] != 0:
+                raise ValueError(
+                    f"batch {Bsz} not divisible by mesh axis "
+                    f"{mesh.shape[batch_axis]}"
+                )
+            sh = lambda nd: mesh_lib.batch_sharding(mesh, nd, batch_axis)
+            A = jax.device_put(A, sh(3))
+            b = jax.device_put(b, sh(2))
+            c = jax.device_put(c, sh(2))
+        else:
+            A, b, c = jnp.asarray(A), jnp.asarray(b), jnp.asarray(c)
+
+        u = jnp.full((Bsz, n), jnp.inf, dtype=dtype)
+        data = jax.vmap(
+            lambda cc, bb, uu: core.make_problem_data(jnp, cc, bb, uu, dtype)
+        )(c, b, u)
+        params = cfg.step_params()
+        setup_time = time.perf_counter() - t0
 
     t1 = time.perf_counter()
     # Phase schedule (shared _phase_plan): phases are auto-gated on
@@ -1614,34 +1670,36 @@ def solve_batched(
     seg = cfg.segment_iters
     if seg is None:
         seg = 8 if jax.default_backend() == "tpu" else 0
-    phase_report = []
-    if seg:
-        (states, status, iters, pinf, dinf, rel_gap, pobj,
-         phase_report) = _solve_batched_segmented(
-            A, data, cfg, params, params_p1, fname, two_phase, seg, cg,
-            compact_ok=mesh is None, fuse_iters=fuse,
-        )
-        # Same row shape chunked or not (the chunked path tags rows in
-        # _concat_results) — consumers never branch on chunking.
-        phase_report = [{**ph, "chunk": 0} for ph in phase_report]
-    else:
-        states, status, iters, pinf, dinf, rel_gap, pobj = _solve_batched_jit(
-            A,
-            data,
-            jnp.asarray(cfg.reg_dual, dtype),
-            params,
-            params_p1,
-            cfg.max_iter,
-            cfg.max_refactor,
-            cfg.reg_grow,
-            fname,
-            two_phase,
-            cfg.stall_window,
-            cg[0],
-            cg[1],
-            fuse,
-        )
-    jax.block_until_ready(states)
+    phase_report, phase_its = [], None
+    with obs_trace.span("batch.loop", chunk=ci, size=Bsz):
+        if seg:
+            (states, status, iters, pinf, dinf, rel_gap, pobj,
+             phase_report) = _solve_batched_segmented(
+                A, data, cfg, params, params_p1, fname, two_phase, seg,
+                counters, cg, compact_ok=mesh is None, fuse_iters=fuse,
+            )
+        else:
+            (states, status, iters, pinf, dinf, rel_gap, pobj,
+             phase_its) = _solve_batched_jit(
+                A,
+                data,
+                jnp.asarray(cfg.reg_dual, dtype),
+                params,
+                params_p1,
+                cfg.max_iter,
+                cfg.max_refactor,
+                cfg.reg_grow,
+                fname,
+                two_phase,
+                cfg.stall_window,
+                cg[0],
+                cg[1],
+                fuse,
+            )
+        jax.block_until_ready((states, status, iters, pinf, dinf, rel_gap,
+                               pobj))
+    # Same row shape chunked or not — consumers never branch on chunking.
+    phase_report = [{**ph, "chunk": ci} for ph in phase_report]
 
     code_map = {
         _OPTIMAL: Status.OPTIMAL,
@@ -1649,17 +1707,23 @@ def solve_batched(
         _NUMERR: Status.NUMERICAL_ERROR,
         _STALL: Status.STALLED,
     }
-    status_arr = np.array(
-        [code_map[int(sc)] for sc in np.asarray(status)], dtype=object
-    )
-    # .array (not .asarray): device arrays convert to read-only views and
-    # the solo cleanup below writes per-member rows.
-    objective = np.array(pobj, dtype=np.float64)
-    x = np.array(states.x, dtype=np.float64)
-    iterations = np.array(iters)
-    rel_gap = np.array(rel_gap, dtype=np.float64)
-    pinf = np.array(pinf, dtype=np.float64)
-    dinf = np.array(dinf, dtype=np.float64)
+    with obs_trace.span("batch.readback"):
+        status_arr = np.array(
+            [code_map[int(sc)] for sc in np.asarray(status)], dtype=object
+        )
+        # .array (not .asarray): device arrays convert to read-only views
+        # and the solo cleanup below writes per-member rows.
+        objective = np.array(pobj, dtype=np.float64)
+        x = np.array(states.x, dtype=np.float64)
+        y = np.array(states.y, dtype=np.float64)
+        iterations = np.array(iters)
+        rel_gap = np.array(rel_gap, dtype=np.float64)
+        pinf = np.array(pinf, dtype=np.float64)
+        dinf = np.array(dinf, dtype=np.float64)
+        if phase_its is not None:
+            counters["lane_iters"] += Bsz * int(np.sum(phase_its))
+    counters["chunks"] += 1
+    counters["member_iters"] += int(iterations.sum())
 
     # Solo cleanup: members the batched loop left unfinished (tail
     # extraction stopped early, stalls, iteration limits) re-solve
@@ -1667,45 +1731,50 @@ def solve_batched(
     # iterates — a handful of solo solves beats keeping the whole batch's
     # masked loop alive at full-batch cost per iteration. Bounded so a
     # pathological batch can't turn into B sequential solves.
-    bad = [i for i in range(Bsz) if status_arr[i] != Status.OPTIMAL]
-    if bad and len(bad) <= _cleanup_cap(Bsz):
-        from distributedlpsolver_tpu.ipm.driver import solve as _solve
+    with obs_trace.span("batch.cleanup"):
+        bad = [i for i in range(Bsz) if status_arr[i] != Status.OPTIMAL]
+        if bad and len(bad) <= _cleanup_cap(Bsz):
+            from distributedlpsolver_tpu.ipm.driver import solve as _solve
 
-        base_cfg = cfg.replace(
-            verbose=False, log_jsonl=None, checkpoint_path=None,
-            checkpoint_every=0, profile_dir=None,
-        )
-        # The batched loop's total budget is max_iter PER PHASE (the f32
-        # phase's accepted steps land in the same per-problem counter;
-        # n_phases from the shared _phase_plan above), so the cleanup
-        # comparison must use the same total — comparing against a single
-        # max_iter would deny tail-extracted members the cleanup solve
-        # the early stop promised them.
-        for i in bad:
-            # The solo solve only gets what the batched loop left unspent
-            # (tail-extracted members keep most of theirs; genuine
-            # iteration-limit members get none and keep that verdict).
-            remaining = n_phases * cfg.max_iter - int(iterations[i])
-            if remaining <= 0:
-                continue
-            solo_cfg = base_cfg.replace(max_iter=remaining)
-            # Per-member host conversion — full-batch f64 copies just to
-            # patch a handful of rows would be ~hundreds of MB transient.
-            inf_i = member_interior_form(batch, i)
-            ws = IPMState(
-                x=x[i],
-                y=np.asarray(states.y[i], dtype=np.float64),
-                s=np.asarray(states.s[i], dtype=np.float64),
-                w=np.asarray(states.w[i], dtype=np.float64),
-                z=np.asarray(states.z[i], dtype=np.float64),
+            base_cfg = cfg.replace(
+                verbose=False, log_jsonl=None, checkpoint_path=None,
+                checkpoint_every=0, profile_dir=None,
             )
-            r = _solve(inf_i, backend=CLEANUP_BACKEND, config=solo_cfg,
-                       warm_start=ws)
-            status_arr[i] = r.status
-            objective[i] = r.objective
-            x[i] = r.x
-            iterations[i] += r.iterations
-            rel_gap[i], pinf[i], dinf[i] = r.rel_gap, r.pinf, r.dinf
+            # The batched loop's total budget is max_iter PER PHASE (the
+            # f32 phase's accepted steps land in the same per-problem
+            # counter; n_phases from the shared _phase_plan above), so
+            # the cleanup comparison must use the same total — comparing
+            # against a single max_iter would deny tail-extracted members
+            # the cleanup solve the early stop promised them.
+            for i in bad:
+                # The solo solve only gets what the batched loop left
+                # unspent (tail-extracted members keep most of theirs;
+                # genuine iteration-limit members get none and keep that
+                # verdict).
+                remaining = n_phases * cfg.max_iter - int(iterations[i])
+                if remaining <= 0:
+                    continue
+                solo_cfg = base_cfg.replace(max_iter=remaining)
+                # Per-member host conversion — full-batch f64 copies just
+                # to patch a handful of rows would be ~hundreds of MB
+                # transient.
+                inf_i = member_interior_form(batch, i)
+                ws = IPMState(
+                    x=x[i],
+                    y=y[i],
+                    s=np.asarray(states.s[i], dtype=np.float64),
+                    w=np.asarray(states.w[i], dtype=np.float64),
+                    z=np.asarray(states.z[i], dtype=np.float64),
+                )
+                r = _solve(inf_i, backend=CLEANUP_BACKEND, config=solo_cfg,
+                           warm_start=ws)
+                counters["cleanup_lps"] += 1
+                status_arr[i] = r.status
+                objective[i] = r.objective
+                x[i] = r.x
+                y[i] = r.y
+                iterations[i] += r.iterations
+                rel_gap[i], pinf[i], dinf[i] = r.rel_gap, r.pinf, r.dinf
 
     solve_time = time.perf_counter() - t1
     return BatchedResult(
@@ -1719,4 +1788,5 @@ def solve_batched(
         solve_time=solve_time,
         setup_time=setup_time,
         phase_report=phase_report,
+        y=y,
     )

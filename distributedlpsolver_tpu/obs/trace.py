@@ -330,14 +330,19 @@ def annotate(name: str, **args) -> None:
 # The span tables and phase reports of this process's last solves, for a
 # caller that did not keep the IPMResult (a benchmark reading stage and
 # phase times after its window): one dict per solve, ``{"t0", "t1",
-# "spans", "phase_report"}``, t0/t1 on the perf_counter clock. Bounded;
-# appends are atomic.
+# "spans", "phase_report", "counters"}``, t0/t1 on the perf_counter
+# clock, ``counters`` None where the solve counts nothing (a batched
+# solve counts its chunks, compactions, iterations and clean-ups).
+# Bounded; appends are atomic.
 _RECENT_SOLVES: collections.deque = collections.deque(maxlen=64)
 
 
-def record_solve(t0: float, t1: float, spans: dict, phase_report) -> None:
+def record_solve(t0: float, t1: float, spans: dict, phase_report,
+                 counters: Optional[dict] = None) -> None:
     _RECENT_SOLVES.append({"t0": t0, "t1": t1, "spans": dict(spans),
-                           "phase_report": phase_report})
+                           "phase_report": phase_report,
+                           "counters": None if counters is None
+                           else dict(counters)})
 
 
 def recent_solves() -> list:

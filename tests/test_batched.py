@@ -138,3 +138,131 @@ def test_final_phase_compaction_matches_plain():
     assert all(s <= 32 for s in calls)
     assert r_comp.n_optimal == r_plain.n_optimal
     np.testing.assert_allclose(r_comp.objective, r_plain.objective, rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# Every route returns each member's duals: x and y certify in float64.
+
+def _standard_form_batch(n_lps, seed=2**31 + 5):
+    """``n_lps`` LPs of 12 x 40 from the lp128x512 configuration's
+    generator (Gaussian A, b = A·x0, c = Aᵀy0 + s0), and their batch."""
+    import os
+
+    from benchmark import spec
+    from distributedlpsolver_tpu.models.generators import BatchedLP
+
+    build = spec.load_module(os.path.join(
+        spec.ROOT, "benchmark", "generators", "standard_form.py")).build
+    params = {"m": 12, "n": 40, "base_seed": 0}
+    problems = [build(params, seed, k) for k in range(n_lps)]
+    return problems, BatchedLP(
+        c=np.stack([p.c for p in problems]),
+        A=np.stack([p.A for p in problems]),
+        b=np.stack([p.rlb for p in problems]), name="sf")
+
+
+def _lp128x512_limits():
+    import json
+    import os
+
+    from benchmark import spec
+
+    with open(os.path.join(spec.ROOT, "benchmark", "configs",
+                           "lp128x512.json")) as fh:
+        return json.load(fh)["limits"]
+
+
+def _demote(monkeypatch, members):
+    """The whole-batch program reports ``members`` unfinished, with their
+    duals halved, so the solo clean-up re-solves them and only the
+    clean-up's own y certifies."""
+    import jax.numpy as jnp
+
+    from distributedlpsolver_tpu.backends import batched
+
+    run = batched._solve_batched_jit
+
+    def demoted(*a, **k):
+        out = run(*a, **k)
+        idx = jnp.asarray(members)
+        states = out[0]._replace(y=out[0].y.at[idx].multiply(0.5))
+        status = out[1].at[idx].set(batched._MAXITER)
+        return (states, status) + tuple(out[2:])
+
+    monkeypatch.setattr(batched, "_solve_batched_jit", demoted)
+
+
+def _spy_cleanup(monkeypatch):
+    """{member: iterations} of every solo clean-up solve."""
+    import re
+
+    from distributedlpsolver_tpu.ipm import driver
+
+    solo, solve_ = {}, driver.solve
+
+    def spy(inf, *a, **k):
+        r = solve_(inf, *a, **k)
+        chunk0, i = re.search(r"(?:\[(\d+):\d+\])?\[(\d+)\]$",
+                              inf.name).groups()
+        solo[int(chunk0 or 0) + int(i)] = int(r.iterations)
+        return r
+
+    monkeypatch.setattr(driver, "solve", spy)
+    return solo
+
+
+# route: (LPs, solve_batched options, members the loop is made to leave
+# unfinished). "segmented" is the chip's route: host segments, compaction
+# (64 -> 32 once the first 10 iterations settle half the batch) and tail
+# extraction.
+ROUTES = {
+    "unchunked": (8, {}, ()),
+    "chunked": (8, {"chunk": 4}, ()),
+    "cleanup": (8, {}, (2, 5)),
+    "segmented": (64, {"segment_iters": 1}, ()),
+}
+
+
+@pytest.mark.parametrize("route", list(ROUTES))
+def test_every_member_certifies_with_its_own_duals(route, monkeypatch):
+    """Each member's x and y form a float64 certificate on its own data
+    (benchmark/references/kkt.py, under the lp128x512 limits) and its
+    objective matches HiGHS's; the call's record carries its spans and
+    counters."""
+    from scipy.optimize import linprog
+
+    from benchmark.references import kkt
+    from distributedlpsolver_tpu.obs import trace as obs_trace
+
+    n_lps, opts, demoted = ROUTES[route]
+    problems, batch = _standard_form_batch(n_lps)
+    if demoted:
+        _demote(monkeypatch, demoted)
+    solo = _spy_cleanup(monkeypatch)
+    r = solve_batched(batch, tol=1e-8, **opts)
+    limits = _lp128x512_limits()
+    assert r.y.shape == (n_lps, 12)
+    for k, p in enumerate(problems):
+        assert r.status[k] == Status.OPTIMAL, k
+        nums = kkt.check(p, {"x": r.x[k], "y": r.y[k]})
+        assert all(v <= limits[n] for n, v in nums.items()), (k, nums)
+        hi = linprog(p.c, A_eq=p.A, b_eq=p.rlb, method="highs")
+        assert r.objective[k] == pytest.approx(hi.fun, rel=1e-7), k
+
+    rec = obs_trace.recent_solves()[-1]
+    assert rec["counters"] == r.counters
+    c = r.counters
+    spans = {"batch.prepare", "batch.loop", "batch.readback",
+             "batch.cleanup"} | ({"batch.compact"} if c["compactions"]
+                                 else set())
+    assert spans <= set(rec["spans"])
+    assert c["chunks"] == (2 if route == "chunked" else 1)
+    assert c["cleanup_lps"] == len(solo)
+    assert set(demoted) <= set(solo)
+    before = r.iterations.copy()
+    for k, its in solo.items():
+        before[k] -= its
+    assert c["member_iters"] == before.sum()
+    assert 0 < c["member_iters"] <= c["lane_iters"]
+    if route == "segmented":
+        assert c["compactions"] >= 1
