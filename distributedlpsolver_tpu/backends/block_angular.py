@@ -59,7 +59,12 @@ class SchurPairs(NamedTuple):
     past the output's end. The target axis is the minor one (the TPU
     compiler took ~35 s over a gather whose minor axis was r=10, ~2 s
     with T there). MLL's lists are one global group whose ``col``
-    indexes ``dBp`` flattened over all K."""
+    indexes ``dBp`` flattened over all K.
+
+    ``w_row[k, l]`` lists the block rows i where ``Gk[k, l, i] ≠ 0``
+    (the distinct targets of the g lists), padded with mb; the
+    downdate gathers ``W = Lk⁻¹Gkᵀ`` from those columns of ``Lk⁻¹``
+    (:func:`_gathered_w`). None where one GEMM forms W instead."""
 
     m_col: jnp.ndarray  # (K, rm, Tm) int32   Mkk = B·D·Bᵀ, target i·mb + j
     m_coef: jnp.ndarray  # (K, rm, Tm)
@@ -70,6 +75,7 @@ class SchurPairs(NamedTuple):
     l_col: jnp.ndarray  # (1, rl, Tl) int32   MLL = Σ_k L·D·Lᵀ, target l·link + l'
     l_coef: jnp.ndarray  # (1, rl, Tl)
     l_tgt: jnp.ndarray  # (1, Tl) int32
+    w_row: Optional[jnp.ndarray]  # (K, link, q) int32, mb pads; or None
 
 
 class BlockTensors(NamedTuple):
@@ -175,6 +181,16 @@ _PAIR_ASSEMBLY_ADVANTAGE = 64
 # padded term for the life of the solve.
 _PAIR_TERMS_MAX = 1 << 24
 
+# The cost of one gathered f64 multiply-add of the downdate's W, in
+# emulated-f64 GEMM flops: W gathers from Gk's q nonzeros a row when
+# q · _GATHER_MAD_COST ≤ 2·mb, the GEMM's flops per entry of W. Timed
+# on one v5e at the pds-10 layout (K 11, mb 1,399, link 1,169, q 2;
+# scripts/probe_downdate.py): the gather, transpose of Lk⁻¹ included,
+# took 7.78 ms for 3.6e7 multiply-adds, the W GEMM 58.6 ms for 5.03e10
+# flops; 186 flops a multiply-add. Blocks of fewer than 186 rows then
+# keep the GEMM even at q = 2.
+_GATHER_MAD_COST = 186
+
 
 def _rank_within(groups: np.ndarray) -> np.ndarray:
     """Each index's rank, ascending, among the indices of its group id —
@@ -238,6 +254,23 @@ def _pad_targets(blk, tgt, col, coef, K: int, size: int, sentinel: int,
             out_tgt.astype(np.int32))
 
 
+def _gk_row_support(g_tgt: np.ndarray, link: int, mb: int):
+    """``(rows, q)``: ``rows[k, l]`` the block rows i of Gk row l's
+    nonzeros, read off the g lists' targets ``l·mb + i``, padded with mb
+    to a width rounded up by :func:`_bucket`; q the widest row's count."""
+    K = g_tgt.shape[0]
+    blk, slot = np.nonzero(g_tgt < link * mb)
+    tgt = g_tgt[blk, slot].astype(np.int64)
+    row = blk * link + tgt // mb
+    cnt = np.bincount(row, minlength=K * link)
+    q = int(cnt.max(initial=0))
+    # Targets ascend within a block, so each row's nonzeros are adjacent.
+    rank = np.arange(row.size) - (np.cumsum(cnt) - cnt)[row]
+    rows = np.full((K, link, _bucket(q)), mb, np.int32)
+    rows[blk, tgt // mb, rank] = tgt % mb
+    return rows, q
+
+
 def schur_pairs(A: sp.spmatrix, block_of_col, row_block, lay: BlockLayout,
                 dtype):
     """Pair lists for the f64c Schur assembly, or None where the dense
@@ -252,9 +285,19 @@ def schur_pairs(A: sp.spmatrix, block_of_col, row_block, lay: BlockLayout,
     ``2·K·nb·(mb² + link·mb + link²)`` and under ``_PAIR_TERMS_MAX``.
     ``report`` holds ``assembly`` ("pairs" | "dense"), ``pairs`` (the
     pair terms, unpadded), ``padded_pairs`` (the terms the device
-    multiplies, 0 for dense) and ``dense_flops_avoided``."""
+    multiplies, 0 for dense) and ``dense_flops_avoided``.
+
+    The same call decides how the linking downdate forms ``W = Lk⁻¹Gkᵀ``
+    (:func:`_block_ops_f64c`): by gathering ≤ q columns of ``Lk⁻¹`` a
+    linking row when the pairs are taken and q (padded) ·
+    ``_GATHER_MAD_COST`` ≤ 2·mb, else by one GEMM. ``report`` adds
+    ``downdate`` ("gather" | "gemm"), ``downdate_q`` (the most nonzeros
+    in a row of Gk, 0 where no pairs were enumerated) and
+    ``downdate_flops_avoided`` against the three-GEMM form
+    ``S −= Gk·(Lk⁻ᵀ·(Lk⁻¹·Gkᵀ))``."""
     K, mb, nb, link, n0, n, m = lay
     dense = 2.0 * K * nb * (mb * mb + link * mb + link * link)
+    gemm_w = 2.0 * K * mb * mb * link  # Lk⁻¹·Gkᵀ, or Lk⁻ᵀ·(…)
     A = sp.csc_matrix(A, copy=True)
     A.sum_duplicates()
     A.eliminate_zeros()
@@ -267,7 +310,8 @@ def schur_pairs(A: sp.spmatrix, block_of_col, row_block, lay: BlockLayout,
     nL = np.bincount(c_[isL], minlength=n).astype(np.float64)
     count = int(np.sum(nB * nB + nL * nB + nL * nL))
     report = {"assembly": "dense", "pairs": count, "padded_pairs": 0,
-              "dense_flops_avoided": 0.0}
+              "dense_flops_avoided": 0.0, "downdate": "gemm",
+              "downdate_q": 0, "downdate_flops_avoided": gemm_w}
     if count * _PAIR_ASSEMBLY_ADVANTAGE > dense or count > _PAIR_TERMS_MAX:
         return None, report
     row_rank = _rank_within(row_block)
@@ -290,7 +334,15 @@ def schur_pairs(A: sp.spmatrix, block_of_col, row_block, lay: BlockLayout,
         return None, report
     report.update(assembly="pairs", padded_pairs=padded,
                   dense_flops_avoided=dense)
-    return (mkk, gk, mll), report
+    rows, q = _gk_row_support(gk[2], link, mb)
+    qp = rows.shape[-1]
+    report["downdate_q"] = q
+    if qp * _GATHER_MAD_COST > 2 * mb:
+        rows = None
+    else:
+        report.update(downdate="gather", downdate_flops_avoided=(
+            2.0 * gemm_w - 2.0 * K * link * qp * mb))
+    return (mkk, gk, mll, rows), report
 
 
 def build_tensors(
@@ -345,11 +397,12 @@ def build_tensors(
         assembly.update(report)
     pairs = None
     if lists is not None:
-        (mc, mf, mt), (gc, gf, gt), (lc, lf, lt) = lists
+        (mc, mf, mt), (gc, gf, gt), (lc, lf, lt), rows = lists
         pairs = SchurPairs(
             put(mc, "blocked"), put(mf, "blocked"), put(mt, "blocked"),
             put(gc, "blocked"), put(gf, "blocked"), put(gt, "blocked"),
             put(lc, "rep"), put(lf, "rep"), put(lt, "rep"),
+            None if rows is None else put(rows, "blocked"),
         )
     tensors = BlockTensors(
         B_all=put(B_all.astype(dtype), "blocked"),
@@ -714,6 +767,26 @@ def _pair_sum(col, coef, tgt, d, size: int):
     return jax.vmap(one)(col, coef, tgt, d)
 
 
+def _gathered_w(Lki, Gk, rows):
+    """``Wᵀ = (Lk⁻¹Gkᵀ)ᵀ``, shape ``(k, link, mb)``, from Gk's nonzeros:
+    row l is ``Σ_q Gk[l, i_q] · Lk⁻ᵀ[i_q, :]`` over ``i_q = rows[k, l,
+    q]``, a padded ``i_q = mb`` reading a zero coefficient. Rows of
+    ``Lk⁻ᵀ`` rather than columns of ``Lk⁻¹`` keep the gather's minor
+    axis the long one (see :class:`SchurPairs`); one pass a q holds one
+    gathered slab besides W."""
+    mb = Lki.shape[-1]
+    coef = jnp.take_along_axis(Gk, rows, axis=2, mode="fill", fill_value=0)
+    LkiT = jnp.swapaxes(Lki, 1, 2)
+    idx = jnp.minimum(rows, mb - 1)
+
+    def term(j, Wt):
+        got = jax.vmap(lambda L, i: L[i])(LkiT, idx[:, :, j])
+        return Wt + coef[:, :, j, None] * got
+
+    return jax.lax.fori_loop(0, rows.shape[2], term,
+                             jnp.zeros(Gk.shape[:2] + (mb,), Lki.dtype))
+
+
 def _block_ops_f64c(t: BlockTensors, lay: BlockLayout, reg,
                     chunk: Optional[int] = None, link_shard=None):
     """Full-precision direct Schur LinOps for HUGE shapes (the block
@@ -739,8 +812,15 @@ def _block_ops_f64c(t: BlockTensors, lay: BlockLayout, reg,
     :func:`schur_pairs`), Mkk, Gk and MLL are built from them instead:
     gather d at each pair's column, multiply by the pair's coefficient,
     sum per target and write each target once — the same f64 products
-    summed in another order, and no chunked einsum runs. The factors,
-    Hk, S and the solves are the same on both assemblies.
+    summed in another order, and no chunked einsum runs.
+
+    The linking downdate ``S −= Σₖ Gk·Mkk⁻¹·Gkᵀ`` runs as the Gram
+    product ``Σₖ WₖᵀWₖ`` of ``W = Lk⁻¹Gkᵀ``: one GEMM, with k and the
+    block rows contracted together, and ``Mkk⁻¹Gkᵀ`` never formed. Where
+    ``t.pairs.w_row`` is set (Gk has few nonzeros a row, see
+    :func:`schur_pairs`) W is gathered from ≤ q columns of ``Lk⁻¹`` a
+    linking row (:func:`_gathered_w`), else formed by one GEMM. The
+    factors and the solves are the same on every form.
     """
     K, mb, nb, link, n0, n, m = lay
     if chunk is None:
@@ -749,6 +829,7 @@ def _block_ops_f64c(t: BlockTensors, lay: BlockLayout, reg,
     base = _block_ops(t, lay, reg, None)  # ew-f64 mat/rmatvec shared
     use_mxu = _chol_mxu_here(t.B_all.dtype)
     pairs = t.pairs
+    w_row = None if pairs is None else pairs.w_row
 
     def chunked(Bfull, Lfull, dfull):
         """One K group's Mkk, Gk and MLL share by n-chunked einsums."""
@@ -851,10 +932,13 @@ def _block_ops_f64c(t: BlockTensors, lay: BlockLayout, reg,
                     jnp.linalg.cholesky(_rel_diag_reg(Mkk, reg)), eye_b,
                     lower=True,
                 )
-            # H_k = M_kk⁻¹ G_kᵀ via two batched GEMMs with Lk⁻¹
-            tmp = jnp.einsum("kmp,klp->kml", Lki, Gk)  # Lk⁻¹ Gkᵀ
-            Hk = jnp.einsum("kpm,kpl->kml", Lki, tmp)  # Lk⁻ᵀ (…)
-            S = S - jnp.einsum("klm,kmp->lp", Gk, Hk)
+            # Gk·Mkk⁻¹·Gkᵀ = WᵀW, W = Lk⁻¹Gkᵀ; k and p contract together
+            # into one GEMM, as the S einsums of _block_ops do.
+            if w_row is not None:
+                Wt = _gathered_w(Lki, Gk, w_row[s : s + g])
+            else:
+                Wt = jnp.einsum("kli,kpi->klp", Gk, Lki)
+            S = S - jnp.einsum("klp,kjp->lj", Wt, Wt)
             Lki_parts.append(Lki)
             Gk_parts.append(Gk)
         if n0:
@@ -1235,14 +1319,19 @@ class BlockAngularBackend(SolverBackend):
         )
         # The f64 path's per-iteration work as the chip runs it: the pair
         # assembly (one multiply-add per padded term) where f64c takes
-        # it, else the dense einsums' flops. The assembly report goes in
-        # the lp.build span and each f64c phase_report row.
+        # it, else the dense einsums' flops; f64c adds its downdate, the
+        # three-GEMM form's flops less what the rule avoided. The
+        # assembly report goes in the lp.build span and each f64c
+        # phase_report row.
         self._f64_flops = self._dense_flops
         if self._huge_f64:
             if self.assembly["assembly"] == "pairs":
                 self._f64_flops = factor_flops + 2.0 * self.assembly[
                     "padded_pairs"
                 ]
+            self._f64_flops += 2.0 * K * link * mb * (2 * mb + link) - (
+                self.assembly["downdate_flops_avoided"]
+            )
             obs_trace.annotate("lp.build", **self.assembly)
 
     def _point_args(self):

@@ -338,31 +338,132 @@ def test_f64c_pair_assembly_matches_chunked(monkeypatch):
                                rtol=1e-12, atol=1e-12)
 
 
+def _hk_form_link_inverse(t, lay, d, reg):
+    """Lsi by the three-GEMM downdate, written out in NumPy:
+    ``S = MLL − Σₖ Gk·Hk``, ``Hk = Mkk⁻¹Gkᵀ``, each matrix with the
+    factorization's relative diagonal regularization."""
+    from scipy.linalg import solve_triangular
+
+    B_, L_ = np.asarray(t.B_all), np.asarray(t.L_all)
+    dB = np.r_[np.asarray(d), 0.0][np.asarray(t.col_idx)]
+    idx = np.arange(lay.mb)
+    Mkk = np.einsum("kmn,kn,kpn->kmp", B_, dB, B_)
+    Mkk[:, idx, idx] += (np.asarray(t.row_idx) == lay.m)
+    Mkk[:, idx, idx] *= 1 + reg
+    Gk = np.einsum("kln,kn,kmn->klm", L_, dB, B_)
+    Hk = np.linalg.solve(Mkk, np.swapaxes(Gk, 1, 2))
+    A0 = np.asarray(t.A0)
+    S = (np.einsum("kln,kn,kpn->lp", L_, dB, L_)
+         + (A0 * np.asarray(d)[np.asarray(t.border_idx)]) @ A0.T
+         - np.einsum("klm,kmp->lp", Gk, Hk))
+    S[np.arange(lay.link), np.arange(lay.link)] *= 1 + reg
+    return solve_triangular(np.linalg.cholesky(S), np.eye(lay.link),
+                            lower=True)
+
+
+def _downdate_rule(monkeypatch, B, downdate):
+    """Set the rule's gather cost so these blocks take ``downdate``: at
+    the chip's cost (``_GATHER_MAD_COST``) blocks of under 186 rows keep
+    the GEMM, and these have 24–32."""
+    monkeypatch.setattr(B, "_GATHER_MAD_COST",
+                        1 if downdate == "gather" else 10**30)
+
+
+@pytest.mark.parametrize("downdate", ["gather", "gemm"])
+def test_f64c_downdate_forms_match_the_hk_form(monkeypatch, downdate):
+    """The linking downdate as WᵀW, W = Lk⁻¹Gkᵀ gathered from Gk's
+    nonzeros or formed by one GEMM, gives the three-GEMM ``Gk·Hk`` form's
+    linking factor and solves to round-off, and the two W forms agree,
+    with a ragged K grouping (5 + 5 + 2) and one dead block."""
+    import jax.numpy as jnp
+
+    from distributedlpsolver_tpu.backends import block_angular as B
+
+    _downdate_rule(monkeypatch, B, downdate)
+    inf = to_interior_form(_network_lp(11, 32, 24, 8, seed=3))
+    rep = {}
+    t, lay = B.build_tensors(inf, jnp.float64, pad_blocks=1, assembly=rep)
+    assert rep["assembly"] == "pairs" and rep["downdate"] == downdate
+    assert (t.pairs.w_row is None) == (downdate == "gemm")
+    monkeypatch.setattr(B, "_K_GROUP", 5)
+    reg = 1e-10
+    rng = np.random.default_rng(5)
+    d = jnp.asarray(rng.uniform(0.5, 2.0, lay.n))
+    r = jnp.asarray(rng.standard_normal(lay.m))
+    ops = B._block_ops_f64c(t, lay, jnp.asarray(reg, jnp.float64), chunk=7)
+    Lki, Lsi, Gk = ops.factorize(d)
+    x = np.asarray(ops.solve((Lki, Lsi, Gk), r))
+    Lsi_hk = _hk_form_link_inverse(t, lay, d, reg)
+    np.testing.assert_allclose(np.asarray(Lsi), Lsi_hk, rtol=1e-12,
+                               atol=1e-12)
+    np.testing.assert_allclose(
+        x, np.asarray(ops.solve((Lki, jnp.asarray(Lsi_hk), Gk), r)),
+        rtol=1e-12, atol=1e-12)
+    if downdate == "gather":
+        t_gemm = t._replace(pairs=t.pairs._replace(w_row=None))
+        ops_m = B._block_ops_f64c(t_gemm, lay, jnp.asarray(reg), chunk=7)
+        f_m = ops_m.factorize(d)
+        np.testing.assert_allclose(np.asarray(Lsi), np.asarray(f_m[1]),
+                                   rtol=1e-12, atol=1e-12)
+        np.testing.assert_allclose(x, np.asarray(ops_m.solve(f_m, r)),
+                                   rtol=1e-12, atol=1e-12)
+
+
 def test_assembly_rule_follows_block_sparsity():
     """Node-arc incidence blocks take the pairs, dense random tiles the
-    einsums; the pair count is every pair of nonzeros sharing a column,
+    einsums; the downdate gathers W on network blocks long enough for
+    q·``_GATHER_MAD_COST`` ≤ 2·mb and takes the GEMM on shorter ones and
+    on dense tiles. The pair count is every pair of nonzeros sharing a
+    column, and ``downdate_q`` the most nonzeros in a row of Gk, both
     counted here from the padded tensors."""
     import jax.numpy as jnp
 
     from distributedlpsolver_tpu.backends import block_angular as B
 
-    for p, want in (
-        (_network_lp(11, 32, 24, 8, seed=3), "pairs"),
-        (block_angular_lp(4, 12, 30, 8, seed=1, sparse=False), "dense"),
+    for p, want, downdate in (
+        (_network_lp(3, 200, 40, 8, seed=1), "pairs", "gather"),
+        (_network_lp(11, 32, 24, 8, seed=3), "pairs", "gemm"),
+        (block_angular_lp(4, 12, 30, 8, seed=1, sparse=False), "dense",
+         "gemm"),
     ):
         rep = {}
         t, lay = B.build_tensors(to_interior_form(p), jnp.float64,
                                  assembly=rep)
         assert rep["assembly"] == want
         assert (t.pairs is None) == (want == "dense")
-        nB = np.count_nonzero(np.asarray(t.B_all), axis=1)
-        nL = np.count_nonzero(np.asarray(t.L_all), axis=1)
+        B_, L_ = np.asarray(t.B_all), np.asarray(t.L_all)
+        nB = np.count_nonzero(B_, axis=1)
+        nL = np.count_nonzero(L_, axis=1)
         assert rep["pairs"] == int(np.sum(nB * nB + nL * nB + nL * nL))
         dense = 2.0 * lay.K * lay.nb * (
             lay.mb**2 + lay.link * lay.mb + lay.link**2)
         assert rep["dense_flops_avoided"] == (dense if want == "pairs" else 0)
         assert rep["padded_pairs"] * B._PAIR_ASSEMBLY_ADVANTAGE <= (
             dense if want == "pairs" else 0)
+        assert rep["downdate"] == downdate
+        gemm_w = 2.0 * lay.K * lay.mb**2 * lay.link
+        if want == "dense":
+            # Nothing enumerated: q is not counted, the Hk GEMM still goes.
+            assert rep["downdate_q"] == 0
+            assert rep["downdate_flops_avoided"] == gemm_w
+            continue
+        G_nz = np.einsum("kln,kmn->klm", L_ != 0, B_ != 0)
+        q = int(np.count_nonzero(G_nz, axis=2).max())
+        assert rep["downdate_q"] == q
+        if downdate == "gemm":
+            assert t.pairs.w_row is None
+            assert B._bucket(q) * B._GATHER_MAD_COST > 2 * lay.mb
+            assert rep["downdate_flops_avoided"] == gemm_w
+            continue
+        rows = np.asarray(t.pairs.w_row)
+        assert rows.shape == (lay.K, lay.link, B._bucket(q))
+        assert rows.shape[2] * B._GATHER_MAD_COST <= 2 * lay.mb
+        got = np.zeros_like(G_nz, dtype=bool)
+        k, l, _ = np.nonzero(rows < lay.mb)
+        got[k, l, rows[rows < lay.mb]] = True
+        np.testing.assert_array_equal(got, G_nz > 0)
+        assert rep["downdate_flops_avoided"] == (
+            2 * gemm_w - 2.0 * lay.K * lay.link * rows.shape[2] * lay.mb)
 
 
 def _forced_f64c(monkeypatch):
@@ -375,11 +476,16 @@ def _forced_f64c(monkeypatch):
     return B
 
 
-def test_f64c_finisher_same_iterations_under_both_assemblies(monkeypatch):
+@pytest.mark.parametrize("downdate", ["gather", "gemm"])
+def test_f64c_finisher_same_iterations_under_both_assemblies(monkeypatch,
+                                                            downdate):
     """Every iteration and the starting point in f64c (factor_dtype
     float64 drops the f32 phase): the pair assembly and the chunked
-    einsums take the same path to 1e-8."""
+    einsums take the same path to 1e-8, with the pairs' downdate W
+    gathered or formed by one GEMM, as the rule's cost is set (the
+    chunked einsums always take the GEMM)."""
     B = _forced_f64c(monkeypatch)
+    _downdate_rule(monkeypatch, B, downdate)
     p = _network_lp(6, 24, 24, 8, seed=7)
     runs = {}
     for advantage, want in ((B._PAIR_ASSEMBLY_ADVANTAGE, "pairs"),
@@ -389,6 +495,8 @@ def test_f64c_finisher_same_iterations_under_both_assemblies(monkeypatch):
         r = solve(p, backend=be, segment_iters=4, factor_dtype="float64",
                   verbose=False)
         assert be.assembly["assembly"] == want
+        assert be.assembly["downdate"] == (
+            downdate if want == "pairs" else "gemm")
         assert r.status == Status.OPTIMAL
         assert r.rel_gap <= 1e-8 and r.pinf <= 1e-8 and r.dinf <= 1e-8
         assert [(ph["mode"], ph["iters"]) for ph in r.phase_report] == [
@@ -409,6 +517,7 @@ def test_f64c_phase_report_and_build_span_carry_the_assembly(
     from distributedlpsolver_tpu.obs import trace as obs_trace
 
     B = _forced_f64c(monkeypatch)
+    _downdate_rule(monkeypatch, B, "gather")
     be = B.BlockAngularBackend()
     tracer = obs_trace.Tracer(str(tmp_path / "trace.json"))
     prev = obs_trace.set_tracer(tracer)
@@ -422,28 +531,43 @@ def test_f64c_phase_report_and_build_span_carry_the_assembly(
     assert rows["f64c"]["assembly"] == "pairs"
     assert rows["f64c"]["pairs"] == be.assembly["pairs"] > 0
     assert rows["f64c"]["dense_flops_avoided"] > 0
+    assert rows["f64c"]["downdate"] == "gather"
+    assert rows["f64c"]["downdate_q"] == be.assembly["downdate_q"] == 2
+    assert rows["f64c"]["downdate_flops_avoided"] > 0
     assert "assembly" not in rows["mixed"]
-    # The f64c row's rate is folded with the pair assembly's flops.
-    assert rows["f64c"]["flops_per_iter"] == be._f64_flops
+    # The f64c row's rate is folded with the pair assembly's flops and
+    # the downdate's: the Gram GEMM and the gather of W.
+    K, mb, link = be._lay.K, be._lay.mb, be._lay.link
+    q = be._tensors.pairs.w_row.shape[2]
+    assert rows["f64c"]["flops_per_iter"] == be._f64_flops == (
+        K * mb**3 / 3.0 + 2.0 * link * link * be._lay.n0 + link**3 / 3.0
+        + 2.0 * be.assembly["padded_pairs"]
+        + 2.0 * K * link * link * mb + 2.0 * K * link * q * mb)
     assert rows["mixed"]["flops_per_iter"] == be._dense_flops > be._f64_flops
     events = json.loads(open(tracer.close()).read())["traceEvents"]
     build = [e for e in events if e["name"] == "lp.build"]
     assert build and build[0]["args"] == be.assembly
+    assert build[0]["args"]["downdate"] == "gather"
 
 
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 virtual devices")
-def test_f64c_pair_assembly_on_a_mesh_matches_unsharded():
-    """Pair lists shard over the block axis like B_all (6 blocks over 8
-    devices: two dead ones) and the linking factor over the mesh; the
-    f64c solve matches the unsharded chunked one."""
+def test_f64c_pair_assembly_on_a_mesh_matches_unsharded(monkeypatch):
+    """Pair lists and the downdate's gather rows shard over the block
+    axis like B_all (6 blocks over 8 devices: two dead ones) and the
+    linking factor over the mesh; the f64c solve matches the unsharded
+    chunked one."""
     import jax.numpy as jnp
 
     from distributedlpsolver_tpu.backends import block_angular as B
 
+    _downdate_rule(monkeypatch, B, "gather")
     inf = to_interior_form(_network_lp(6, 24, 24, 8, seed=7))
     be = B.BlockAngularBackend(mesh=make_mesh(axis_names=("blocks",)))
     be.setup(inf, SolverConfig())
     assert be._lay.K == 8 and be.assembly["assembly"] == "pairs"
+    # The gathered downdate runs sharded: its rows lie with their blocks.
+    assert be.assembly["downdate"] == "gather"
+    assert be._tensors.pairs.w_row.sharding.spec[0] == "blocks"
     t, lay = B.build_tensors(inf, jnp.float64)
     reg = jnp.asarray(1e-10, jnp.float64)
     rng = np.random.default_rng(2)
@@ -461,13 +585,16 @@ def test_f64c_pair_assembly_on_a_mesh_matches_unsharded():
         np.asarray(ops.solve(ops.factorize(d), r)), rtol=1e-10, atol=1e-10)
 
 
-def test_pair_lists_of_one_layout_share_a_padded_shape():
+def test_pair_lists_of_one_layout_share_a_padded_shape(monkeypatch):
     """Two networks of one layout with other random arcs get pair lists
-    of one padded shape, so a second LP reuses the first's programs: T
-    and r are rounded up to a quarter of their power of two."""
+    (the downdate's gather rows among them) of one padded shape, so a
+    second LP reuses the first's programs: T, r and q are rounded up to
+    a quarter of their power of two."""
     import jax.numpy as jnp
 
     from distributedlpsolver_tpu.backends import block_angular as B
+
+    _downdate_rule(monkeypatch, B, "gather")
 
     assert [B._bucket(x) for x in (0, 1, 7, 8, 9, 11, 9959, 9969)] == [
         1, 1, 7, 8, 10, 12, 10240, 10240]
